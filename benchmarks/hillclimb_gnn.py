@@ -75,7 +75,7 @@ def lower_partitioned(cfg, mesh, caps, d_feat):
     state_abs = {"params": params_abs,
                  "opt": jax.eval_shape(adamw_init, params_abs)}
     step = make_partitioned_gin_step(cfg, mesh, caps)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step).lower(state_abs, batch_abs).compile()
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost

@@ -1,9 +1,6 @@
 """repro — out-of-core edge partitioning (2PS-L) + the SPMD runtime it feeds.
 
-Importing the package installs the small JAX compat shim (see ``_compat``)
-so the newer mesh API spelling used throughout the codebase works on the
-pinned jax version.
+Importing the package touches no JAX state: the platform comes from JAX's
+own configuration (``JAX_PLATFORMS``), and the persistent compile cache is
+placed by the launchers (``repro.compile_cache``), never at import.
 """
-from . import _compat
-
-_compat.install()

@@ -106,12 +106,16 @@ def set_jnp(bm: jnp.ndarray, v: jnp.ndarray, p: jnp.ndarray,
         w = jnp.where(mask, w, jnp.int32(bm.size))  # out-of-range => dropped
         bit = jnp.where(mask, bit, jnp.uint32(0))
     lin_s, or_scan, is_last = _segment_or_last(w, bit)
-    flat = bm.reshape(-1)
-    upd = flat[jnp.clip(lin_s, 0, bm.size - 1)] | or_scan
-    idx = jnp.where(is_last, lin_s, jnp.int32(bm.size))
-    flat = flat.at[idx].set(jnp.where(is_last, upd, jnp.uint32(0)),
-                            mode="drop")
-    return flat.reshape(bm.shape)
+    # gather and scatter with (row, word) indices: a flat view of the
+    # (V, words) matrix would be a relayout copy on TPU (words < 128 lanes
+    # pads 16x at k=256), and slow to compile
+    lin = jnp.clip(lin_s, 0, bm.size - 1)
+    row, col = lin // n_words, lin % n_words
+    upd = bm[row, col] | or_scan
+    keep = is_last & (lin_s < bm.size)          # masked updates: dropped
+    row = jnp.where(keep, row, jnp.int32(bm.shape[0]))
+    return bm.at[row, col].set(jnp.where(keep, upd, jnp.uint32(0)),
+                               mode="drop")
 
 
 def popcount_jnp(bm: jnp.ndarray) -> jnp.ndarray:

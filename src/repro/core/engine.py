@@ -238,6 +238,15 @@ def merge_state_dicts(base: dict, shards, rules: dict) -> dict:
     return out
 
 
+def run_environment(part) -> dict:
+    """What actually ran, for a run's ``extras``: the device platform and,
+    for partitioners that score, the resolved scoring backend."""
+    env = {"platform": jax.devices()[0].platform}
+    if hasattr(part, "backend"):
+        env["scoring_backend"] = part.backend
+    return env
+
+
 def _set_replication_gauge(part, state, metrics) -> None:
     """Refresh ``engine.replication_state_bytes``: budgeted partitioners
     (HEP) report their pinned footprint; everyone else the replication
@@ -1146,6 +1155,7 @@ def _run_spec_traced(spec, stream, k, out_path, degrees, tracer, metrics,
     io_retries = getattr(stream, "retries", None)
     if io_retries is not None:
         extras["io_retries"] = int(io_retries)
+    extras.update(run_environment(part))
     if getattr(part, "num_hosts", 0):
         # hierarchy-aware quality: how many host groups each vertex spans
         # (== the DCN synchronization volume a host-grouped halo exchange

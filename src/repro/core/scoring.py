@@ -4,12 +4,12 @@ These are the pure math shared by the core partitioner, the Pallas kernels'
 reference oracles, and the baselines.  Everything is expressed over already
 *gathered* per-edge quantities so it works identically under numpy and jnp.
 
-``resolve_scoring_backend`` maps a ``PartitionerSpec.scoring_backend``
-request onto what this host can actually execute: ``"pallas"`` routes the
-chunk kernels' score/argmax inner loop through the fused VMEM kernels in
+``resolve_scoring_backend`` checks a ``PartitionerSpec.scoring_backend``
+request against what this host can execute: ``"pallas"`` routes the chunk
+kernels' score/argmax inner loop through the fused VMEM kernels in
 ``repro.kernels.edge_score`` / ``repro.kernels.hdrf_score`` (compiled on
-TPU, interpret mode elsewhere), and silently degrades to ``"jnp"`` when the
-Pallas path cannot run in this jax build.
+TPU, interpret mode elsewhere).  A Pallas path that cannot run raises with
+the compiler's message; it never turns into ``"jnp"``.
 """
 from __future__ import annotations
 
@@ -20,18 +20,15 @@ import jax.numpy as jnp
 
 @functools.lru_cache(maxsize=None)
 def resolve_scoring_backend(requested: str = "jnp") -> str:
-    """'pallas' if requested AND both scoring kernels pass their one-time
-    availability probe; 'jnp' otherwise."""
-    if requested != "pallas":
-        return "jnp"
-    try:
+    """The backend a run uses: ``"jnp"`` or ``"pallas"``.  ``"pallas"``
+    first runs both scoring kernels' one-time probe, which raises when a
+    kernel does not compile or run here."""
+    if requested == "pallas":
         from repro.kernels.edge_score import pallas_ready as _edge_ready
         from repro.kernels.hdrf_score import pallas_ready as _hdrf_ready
-        if _edge_ready() and _hdrf_ready():
-            return "pallas"
-    except Exception:  # pragma: no cover - depends on jax build
-        pass
-    return "jnp"
+        _edge_ready()
+        _hdrf_ready()
+    return requested
 
 
 def host_affinity_penalty(hrep_u, hrep_v, dcn_penalty: float):
@@ -95,6 +92,26 @@ def twopsl_score(du, dv, vol_cu, vol_cv, rep_u, rep_v, cu_on_p, cv_on_p,
     return s
 
 
+def hdrf_terms(du, dv, part_sizes, lam: float = 1.1, eps: float = 1.0):
+    """The divisions of ``hdrf_score``: per edge, the degree term an
+    endpoint adds where it is already replicated (``1 + (1 - theta)``),
+    and per partition the balance term.  The Pallas kernel receives these
+    already computed, so both backends round every division the same way.
+
+    du, dv     : (E,) degrees
+    part_sizes : (k,) current partition sizes
+    returns    : g_u (E, 1), g_v (E, 1), c_bal (k,), all f32
+    """
+    dsum = jnp.maximum((du + dv).astype(jnp.float32), 1.0)[:, None]
+    g_u = 1.0 + (1.0 - du[:, None] / dsum)
+    g_v = 1.0 + (1.0 - dv[:, None] / dsum)
+    maxsize = part_sizes.max().astype(jnp.float32)
+    minsize = part_sizes.min().astype(jnp.float32)
+    c_bal = lam * (maxsize - part_sizes.astype(jnp.float32)) / (
+        eps + maxsize - minsize)
+    return g_u, g_v, c_bal
+
+
 def hdrf_score(du, dv, rep_u, rep_v, part_sizes, lam: float = 1.1,
                eps: float = 1.0, degree_weighted: bool = True,
                hrep_u=None, hrep_v=None, dcn_penalty: float = 0.0):
@@ -112,19 +129,11 @@ def hdrf_score(du, dv, rep_u, rep_v, part_sizes, lam: float = 1.1,
                  ``host_affinity_penalty`` from every candidate
     returns    : (E, k) scores
     """
-    if degree_weighted:
-        dsum = jnp.maximum((du + dv).astype(jnp.float32), 1.0)[:, None]
-        theta_u = du[:, None] / dsum
-        theta_v = dv[:, None] / dsum
-        g_u = jnp.where(rep_u, 1.0 + (1.0 - theta_u), 0.0)
-        g_v = jnp.where(rep_v, 1.0 + (1.0 - theta_v), 0.0)
-    else:
-        g_u = jnp.where(rep_u, 1.0, 0.0)
-        g_v = jnp.where(rep_v, 1.0, 0.0)
-    maxsize = part_sizes.max().astype(jnp.float32)
-    minsize = part_sizes.min().astype(jnp.float32)
-    c_bal = lam * (maxsize - part_sizes.astype(jnp.float32)) / (
-        eps + maxsize - minsize)
+    deg_u, deg_v, c_bal = hdrf_terms(du, dv, part_sizes, lam, eps)
+    if not degree_weighted:
+        deg_u = deg_v = 1.0
+    g_u = jnp.where(rep_u, deg_u, 0.0)
+    g_v = jnp.where(rep_v, deg_v, 0.0)
     s = g_u + g_v + c_bal[None, :]
     if dcn_penalty:
         s = s - host_affinity_penalty(hrep_u, hrep_v, dcn_penalty)

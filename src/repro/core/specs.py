@@ -45,8 +45,8 @@ class PartitionerSpec:
     ``scoring_backend`` selects the implementation of the scoring hot path:
     ``"jnp"`` (XLA-fused jnp, the default) or ``"pallas"`` (the fused
     VMEM-resident kernels in ``repro.kernels.edge_score`` /
-    ``repro.kernels.hdrf_score``; falls back to jnp automatically where
-    Pallas cannot run).
+    ``repro.kernels.hdrf_score``; a kernel that cannot run raises with
+    the compiler's message, it never turns into jnp).
 
     ``host_groups`` / ``dcn_penalty`` make the scoring pass hierarchy-aware
     (arXiv:2103.12594-style locality scoring on top of 2PS-L's two-phase

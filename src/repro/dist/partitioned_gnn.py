@@ -41,7 +41,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import obs
@@ -671,11 +670,11 @@ def make_partitioned_gnn_step(model, cfg, mesh, dims, *, lr=1e-3):
 
     def loss_fn(params, batch):
         body = functools.partial(loss_body, cfg, axes=axes, v_cap=v_cap)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P(), params),
                       jax.tree.map(lambda _: part_spec, batch)),
-            out_specs=P(), check_rep=False)
+            out_specs=P(), check_vma=False)
         return fn(params, batch)
 
     return make_train_step(loss_fn, linear_warmup_cosine(lr, 20, 2_000),

@@ -18,14 +18,13 @@ mesh without edits:
 
 ``constrain`` is the in-model annotation primitive: a no-op outside a mesh
 context (single-process tests and references), ``with_sharding_constraint``
-under the ambient mesh otherwise.
+under the mesh set by ``jax.set_mesh`` otherwise.
 """
 from __future__ import annotations
 
 import numpy as np
 
 import jax
-from jax.interpreters import pxla
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
@@ -37,6 +36,8 @@ def _axis_sizes(mesh) -> dict:
     """{axis name: size} for a jax Mesh or any mesh-shaped stand-in with
     ``axis_names`` + ``devices`` (tests use plain classes)."""
     names = tuple(mesh.axis_names)
+    if isinstance(mesh, jax.sharding.AbstractMesh):   # has no devices
+        return {n: int(s) for n, s in dict(mesh.shape).items()}
     devices = getattr(mesh, "devices", None)
     if devices is not None:
         return dict(zip(names, np.shape(devices)))
@@ -63,7 +64,8 @@ def _group_size(sizes: dict, group: tuple) -> int:
 
 
 def _current_mesh():
-    mesh = pxla.thread_resources.env.physical_mesh
+    """The mesh set by ``jax.set_mesh`` (visible while tracing), or None."""
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else mesh
 
 
@@ -94,8 +96,8 @@ def best_spec(mesh, shape, prefs) -> P:
 
 
 def constrain(x, *axes):
-    """``with_sharding_constraint`` under the ambient mesh; identity when no
-    mesh is active.  ``axes`` are ``(dim, axis_name)`` pairs; ``axis_name``
+    """``with_sharding_constraint`` under the mesh set by ``jax.set_mesh``;
+    identity when no mesh is set.  ``axes`` are ``(dim, axis_name)`` pairs; ``axis_name``
     may be 'fsdp'.  Non-divisible or absent axes are silently skipped so
     model code never has to special-case small/smoke shapes."""
     mesh = _current_mesh()

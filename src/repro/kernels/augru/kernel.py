@@ -33,18 +33,17 @@ def _augru_kernel(xg_ref, u_ref, att_ref, h0_ref, hall_ref, h_scratch, *,
 
     def step(t, _):
         h = h_scratch[...]                       # (BB, Hp)
-        xg = pl.load(xg_ref, (slice(None), pl.dslice(t, 1),
-                              slice(None)))[:, 0, :].astype(jnp.float32)
+        xg = xg_ref[:, pl.ds(t, 1), :][:, 0, :].astype(jnp.float32)
         hU = jax.lax.dot(h, u, preferred_element_type=jnp.float32)
         r = jax.nn.sigmoid(xg[:, :Hp] + hU[:, :Hp])
         z = jax.nn.sigmoid(xg[:, Hp:2 * Hp] + hU[:, Hp:2 * Hp])
         n = jnp.tanh(xg[:, 2 * Hp:] + r * hU[:, 2 * Hp:])
-        a = pl.load(att_ref, (slice(None), pl.dslice(t, 1)))  # (BB, 1)
+        a = att_ref[:, pl.ds(t, 1)]                           # (BB, 1)
         zg = a.astype(jnp.float32) * z           # attention-gated update
         h_new = (1.0 - zg) * h + zg * n
         h_scratch[...] = h_new
-        pl.store(hall_ref, (slice(None), pl.dslice(t, 1), slice(None)),
-                 h_new[:, None, :].astype(hall_ref.dtype))
+        hall_ref[:, pl.ds(t, 1), :] = h_new[:, None, :].astype(
+            hall_ref.dtype)
         return ()
 
     jax.lax.fori_loop(0, T, step, ())

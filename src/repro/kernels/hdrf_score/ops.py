@@ -7,6 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.scoring import hdrf_terms
+
 from .kernel import BLOCK_E, hdrf_pallas
 
 LANES = 128
@@ -14,17 +16,14 @@ LANES = 128
 
 @functools.lru_cache(maxsize=1)
 def pallas_ready() -> bool:
-    """Can the kernel actually run here (compiled on TPU, interpret mode
-    elsewhere)?  Probed once with a tile-sized dummy call; the streaming
-    engine falls back to the jnp scoring path when this is False."""
-    try:
-        z1 = jnp.zeros((1,), jnp.float32)
-        zk = jnp.zeros((1, 2), jnp.int8)
-        jax.block_until_ready(
-            hdrf_choose(z1, z1, zk, zk, jnp.zeros((2,), jnp.int32)))
-        return True
-    except Exception:  # pragma: no cover - depends on jax build
-        return False
+    """Run the kernel once on a tile-sized dummy input (compiled on TPU,
+    interpret mode elsewhere).  Returns True, or raises what the compiler
+    or the runtime raised."""
+    z1 = jnp.zeros((1,), jnp.float32)
+    zk = jnp.zeros((1, 2), jnp.int8)
+    jax.block_until_ready(
+        hdrf_choose(z1, z1, zk, zk, jnp.zeros((2,), jnp.int32)))
+    return True
 
 
 @functools.partial(jax.jit,
@@ -49,14 +48,14 @@ def hdrf_choose(du, dv, rep_u, rep_v, sizes, hrep_u=None, hrep_v=None, *,
     def mat(x):
         return jnp.pad(x.astype(jnp.int8), ((0, pad_e), (0, pad_k)))
 
-    du_p = jnp.pad(du.astype(jnp.float32), (0, pad_e)).reshape(Ep, 1)
-    dv_p = jnp.pad(dv.astype(jnp.float32), (0, pad_e)).reshape(Ep, 1)
+    g_u, g_v, c_bal = hdrf_terms(du, dv, sizes, lam)
+    g_u = jnp.pad(g_u, ((0, pad_e), (0, 0)))
+    g_v = jnp.pad(g_v, ((0, pad_e), (0, 0)))
+    c_bal = jnp.pad(c_bal, (0, pad_k)).reshape(1, -1)
     ru, rv = mat(rep_u), mat(rep_v)
-    sz = jnp.pad(sizes.astype(jnp.float32), (0, pad_k)).reshape(1, -1)
     hu = mat(hrep_u) if dcn_penalty else None
     hv = mat(hrep_v) if dcn_penalty else None
 
-    chosen, best = hdrf_pallas(du_p, dv_p, ru, rv, sz, hu, hv, lam=lam,
-                               k=k, dcn_penalty=dcn_penalty,
-                               interpret=interpret)
+    chosen, best = hdrf_pallas(g_u, g_v, ru, rv, c_bal, hu, hv, k=k,
+                               dcn_penalty=dcn_penalty, interpret=interpret)
     return chosen.reshape(Ep)[:E], best.reshape(Ep)[:E]

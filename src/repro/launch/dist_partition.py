@@ -36,7 +36,7 @@ import os
 import subprocess
 import sys
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import (MemmapEdgeStream, PartitionArtifact,
                         SPEC_REGISTRY, SpecError, spec_for)
 from repro.core.artifact import ASSIGNMENT_FILE
@@ -112,6 +112,7 @@ def main(argv=None):
                          "shard:exchange spans")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.backend == "emulated" and args.rank is not None:
         ap.error("--rank is for --backend fs (emulated runs all workers "
@@ -249,7 +250,19 @@ def main(argv=None):
 def _spawn_fs_workers(args, argv):
     """Parent mode for --backend fs: one subprocess per rank running this
     module with --rank appended.  Rank 0 inherits stdout (it prints the
-    report); other ranks are quiet.  Any nonzero child propagates."""
+    report); other ranks are quiet.  Any nonzero child propagates.
+
+    Every child starts JAX on this host, and an accelerator belongs to one
+    process at a time, so the parent only spawns ranks that were pinned to
+    the CPU (``JAX_PLATFORMS=cpu``); anywhere else it refuses rather than
+    let the ranks contend for one chip."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise SystemExit(
+            "--backend fs without --rank starts every rank on this host, "
+            "and each would claim the same accelerator. Set "
+            "JAX_PLATFORMS=cpu to run the ranks on the CPU, start one rank "
+            "per host with --rank, or use --backend emulated (one worker "
+            "thread per local device).")
     argv = list(sys.argv[1:] if argv is None else argv)
     procs = []
     for r in range(args.workers):

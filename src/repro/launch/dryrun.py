@@ -147,7 +147,7 @@ def _cell_costs(arch_id, shape_name, mesh, *, n_layers=None):
     once, so scanned programs hide (L-1)/L of the per-step work."""
     jitted, args = build_cell(arch_id, shape_name, mesh, n_layers=n_layers,
                               unroll=True)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jitted.lower(*args).compile()
     cost = compiled.cost_analysis()
     if isinstance(cost, list):
@@ -163,7 +163,7 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
     spec = get_arch(arch_id)
     t0 = time.time()
     jitted, args = build_cell(arch_id, shape_name, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -58,7 +58,7 @@ import json
 import os
 import sys
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import (MemmapEdgeStream, PartitionArtifact,
                         SPEC_REGISTRY, SpecError, ThrottledEdgeStream,
                         run_spec, spec_for)
@@ -131,8 +131,9 @@ def main(argv=None):
                          "spec's; 1 = fully synchronous)")
     ap.add_argument("--scoring-backend", default=None,
                     choices=("jnp", "pallas"),
-                    help="scoring hot-path implementation (pallas falls "
-                         "back to jnp where unavailable)")
+                    help="scoring hot-path implementation (pallas fails "
+                         "with the compiler's message where its kernels "
+                         "cannot run; the report names the backend used)")
     ap.add_argument("--pair-cap-quantile", type=float, default=1.0,
                     help="halo-plan boundary-table cap quantile (<1 moves "
                          "over-cap pairs to the psum overflow lane)")
@@ -169,9 +170,10 @@ def main(argv=None):
     ap.add_argument("--jax-profile", default=None, metavar="DIR",
                     help="additionally capture a jax.profiler device "
                          "trace into DIR (view with tensorboard or "
-                         "Perfetto; no-op if the profiler is unavailable)")
+                         "Perfetto; fails if the profiler cannot start)")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.hosts is not None and args.artifact_dir and args.no_plan:
         ap.error("--hosts with --artifact-dir persists the host plan, "
                  "which needs the halo plan --no-plan skips")
@@ -306,6 +308,7 @@ def main(argv=None):
         # under --json keep stdout machine-parseable: table -> stderr
         table = obs.trace_summary_table(stall, registry.snapshot())
         print(table, file=sys.stderr if args.json else sys.stdout)
+    return report
 
 
 def _partition_manifest(args, res, stream, plan=None,

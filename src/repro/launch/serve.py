@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_arch
 from repro.launch import steps as S
 
@@ -254,6 +255,7 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true",
                     help="print a machine-readable report (one JSON object)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.gnn_artifact is not None:
         _, report = serve_gnn(
             args.gnn_artifact, n_requests=args.requests,

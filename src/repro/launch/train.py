@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch
 from repro.launch import steps as S
@@ -90,6 +90,7 @@ def main(argv=None):
                          "straggler instants) to a Chrome trace_event "
                          "JSON at PATH — see docs/observability.md")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     tracer = obs.Tracer() if args.trace else obs.NULL_TRACER
 

@@ -114,24 +114,17 @@ def trace_summary_table(report, metrics_snapshot: dict | None = None) -> str:
 
 @contextlib.contextmanager
 def jax_profiler_session(log_dir: str | None):
-    """Optionally capture a ``jax.profiler`` device trace around the
-    block (TensorBoard/XProf format, complements the host-side span
-    trace: the ``jax.named_scope`` annotations in ``_halo_combine`` and
-    the chunk kernels show up there).  ``log_dir=None`` or an unavailable
-    profiler degrade to a plain pass-through — never a hard dep."""
+    """Capture a ``jax.profiler`` device trace around the block
+    (TensorBoard/XProf format, complements the host-side span trace: the
+    ``jax.named_scope`` annotations in ``_halo_combine`` and the chunk
+    kernels show up there).  ``log_dir=None`` is a plain pass-through; a
+    profiler that cannot start raises."""
     if not log_dir:
         yield False
         return
-    try:
-        import jax
-        jax.profiler.start_trace(log_dir)
-    except Exception:                 # profiler backend missing/unusable
-        yield False
-        return
+    import jax
+    jax.profiler.start_trace(log_dir)
     try:
         yield True
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
+        jax.profiler.stop_trace()

@@ -143,10 +143,6 @@ class JaxDistributedExchange(FileExchange):
                  num_processes=None, process_id=None,
                  timeout_s: float = 300.0, poll_s: float = 0.05):
         import jax
-        if not hasattr(jax, "distributed"):
-            raise RuntimeError(
-                "this JAX build has no jax.distributed; use the 'fs' "
-                "backend (FileExchange) instead")
         try:
             jax.distributed.initialize(
                 coordinator_address=coordinator_address,

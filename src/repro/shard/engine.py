@@ -39,13 +39,14 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.engine import (PartitionRunResult, StallClock, _Timer,
                            _alloc_assignment, _assignment_writer,
                            _run_pass_pipeline, _set_replication_gauge,
-                           build_partitioner)
+                           build_partitioner, run_environment)
 from ..core.metrics import (cross_host_replication_factor,
                             quality_from_bitmatrix)
 from ..obs import get_registry, get_tracer
@@ -371,6 +372,7 @@ def finalize_shard_run(worker: ShardWorkerResult, layout: ShardLayout,
                                          stream.num_edges)
     worker.timer.lap("finalize")
     _set_replication_gauge(part, state, metrics)
+    extras.update(run_environment(part))
     extras["shards"] = layout.world
     extras["round_chunks"] = layout.round_chunks
     extras["rounds"] = layout.num_rounds
@@ -414,15 +416,20 @@ def run_spec_sharded(spec, stream, k, *, num_shards: int,
     results: list = [None] * num_shards
     errors: list = [None] * num_shards
 
+    devices = jax.devices()
+
     def _target(rank):
         try:
-            results[rank] = run_worker(
-                spec, stream, k, hub.for_rank(rank),
-                round_chunks=round_chunks, tracer=tracer,
-                metrics=metrics, retry_policy=retry_policy,
-                checkpoint_dir=checkpoint_dir,
-                checkpoint_every_rounds=checkpoint_every_rounds,
-                resume=resume)
+            # one device per worker where the host has several (on one
+            # device every worker shares it, as before)
+            with jax.default_device(devices[rank % len(devices)]):
+                results[rank] = run_worker(
+                    spec, stream, k, hub.for_rank(rank),
+                    round_chunks=round_chunks, tracer=tracer,
+                    metrics=metrics, retry_policy=retry_policy,
+                    checkpoint_dir=checkpoint_dir,
+                    checkpoint_every_rounds=checkpoint_every_rounds,
+                    resume=resume)
         except BaseException as e:           # propagate to peers + driver
             errors[rank] = e
             hub.abort(e)

@@ -23,17 +23,15 @@ import numpy as np
 import pytest
 
 from repro.core import (InMemoryEdgeStream, PartitionArtifact, SPEC_REGISTRY,
-                        capacity, quality_from_assignment,
-                        resolve_scoring_backend, run_spec, spec_for,
-                        spec_from_dict)
+                        capacity, quality_from_assignment, run_spec,
+                        spec_for, spec_from_dict)
 from conftest import tspec
 
 ALGOS = sorted(SPEC_REGISTRY)
 DEPTHS = (2, 4)
 V, K, CHUNK = 350, 8, 512
 
-_PALLAS = resolve_scoring_backend("pallas") == "pallas"
-BACKENDS = ("jnp", "pallas") if _PALLAS else ("jnp",)
+BACKENDS = ("jnp", "pallas")
 
 
 def test_harness_tracks_registry():

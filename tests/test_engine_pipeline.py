@@ -3,6 +3,7 @@ on-device degree pass, Pallas scoring backend, out-of-core halo planning,
 and property-based engine parity over fuzzed edge streams."""
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -150,13 +151,37 @@ def test_degrees_shortcircuit_matches_inline(seed_graph):
 
 def test_resolve_scoring_backend():
     assert resolve_scoring_backend("jnp") == "jnp"
-    assert resolve_scoring_backend("pallas") in ("jnp", "pallas")
+    assert resolve_scoring_backend("pallas") == "pallas"
+
+
+def test_pallas_probe_failure_raises(monkeypatch):
+    """A Pallas kernel that cannot run fails the run with the compiler's
+    message; it never turns the request into the jnp path."""
+    import repro.kernels.hdrf_score as hdrf_score
+
+    def refused():
+        raise RuntimeError("Mosaic refused the kernel")
+
+    monkeypatch.setattr(hdrf_score, "pallas_ready", refused)
+    resolve_scoring_backend.cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            resolve_scoring_backend("pallas")
+    finally:
+        resolve_scoring_backend.cache_clear()
+
+
+def test_run_reports_platform_and_backend(seed_graph):
+    stream = InMemoryEdgeStream(seed_graph)
+    for backend in ("jnp", "pallas"):
+        res = run_spec(tspec("2psl", _CHUNK, scoring_backend=backend),
+                       stream, 8)
+        assert res.extras["scoring_backend"] == backend
+        assert res.extras["platform"] == jax.devices()[0].platform
 
 
 @pytest.mark.parametrize("name", ["2psl", "2ps-hdrf", "hdrf"])
 def test_pallas_backend_matches_jnp_assignments(name, seed_graph):
-    if resolve_scoring_backend("pallas") != "pallas":
-        pytest.skip("Pallas unavailable in this jax build")
     stream = InMemoryEdgeStream(seed_graph)
     rj = run_spec(tspec(name, _CHUNK), stream, 8)
     rp = run_spec(tspec(name, _CHUNK, scoring_backend="pallas"), stream, 8)
@@ -222,12 +247,11 @@ def test_engine_parity_fuzz(name, case):
     assert base.quality.replication_factor \
         == deep.quality.replication_factor
     assert base.quality.balance == deep.quality.balance
-    if resolve_scoring_backend("pallas") == "pallas":
-        pal = run_spec(tspec(name, chunk, pipeline_depth=depth,
-                             scoring_backend="pallas"), stream, k)
-        np.testing.assert_array_equal(
-            np.asarray(base.assignment), np.asarray(pal.assignment),
-            err_msg=f"{name} jnp vs pallas backend")
+    pal = run_spec(tspec(name, chunk, pipeline_depth=depth,
+                         scoring_backend="pallas"), stream, k)
+    np.testing.assert_array_equal(
+        np.asarray(base.assignment), np.asarray(pal.assignment),
+        err_msg=f"{name} jnp vs pallas backend")
 
 
 # ---------------------------------------------------------------------------

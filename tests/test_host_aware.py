@@ -114,7 +114,6 @@ def test_zero_penalty_bit_identical_across_depths_and_backends(name, graph):
     """The parity the engine fuzz guarantees for flat specs must extend to
     host-grouped zero-penalty specs: depth and scoring backend both leave
     the assignment untouched."""
-    from repro.core import resolve_scoring_backend
     stream = InMemoryEdgeStream(graph, num_vertices=V)
     base = run_spec(spec_for(name, chunk_size=CHUNK, host_groups=2,
                              pipeline_depth=1), stream, K)
@@ -122,20 +121,16 @@ def test_zero_penalty_bit_identical_across_depths_and_backends(name, graph):
                              pipeline_depth=4), stream, K)
     np.testing.assert_array_equal(np.asarray(base.assignment),
                                   np.asarray(deep.assignment))
-    if resolve_scoring_backend("pallas") == "pallas":
-        pal = run_spec(spec_for(name, chunk_size=CHUNK, host_groups=2,
-                                scoring_backend="pallas"), stream, K)
-        np.testing.assert_array_equal(np.asarray(base.assignment),
-                                      np.asarray(pal.assignment))
+    pal = run_spec(spec_for(name, chunk_size=CHUNK, host_groups=2,
+                            scoring_backend="pallas"), stream, K)
+    np.testing.assert_array_equal(np.asarray(base.assignment),
+                                  np.asarray(pal.assignment))
 
 
 @pytest.mark.parametrize("name", ("2psl", "2ps-hdrf", "hdrf"))
 def test_hosted_backends_agree(name, graph):
     """With a nonzero penalty, the jnp and Pallas scoring backends must
     still produce bit-identical assignments."""
-    from repro.core import resolve_scoring_backend
-    if resolve_scoring_backend("pallas") != "pallas":
-        pytest.skip("Pallas unavailable in this jax build")
     stream = InMemoryEdgeStream(graph, num_vertices=V)
     kw = dict(chunk_size=CHUNK, host_groups=2, dcn_penalty=1.5)
     rj = run_spec(spec_for(name, **kw), stream, K)

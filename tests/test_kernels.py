@@ -165,6 +165,30 @@ def test_hdrf_score_host_variant_matches_ref(E, k, hosts, pen):
     np.testing.assert_array_equal(np.asarray(b0), np.asarray(bf))
 
 
+@pytest.mark.parametrize("k", [8, 32, 256])
+def test_hdrf_score_ties_pick_first_partition(k):
+    """Equal scores pick the lowest partition, as jnp.argmax does: every
+    partition ties at the start of a run, so another tie-break would
+    relabel the whole partitioning against the jnp backend."""
+    from repro.kernels.hdrf_score import hdrf_choose, hdrf_choose_ref
+    E = 16
+    du = jnp.full((E,), 3, jnp.int32)
+    dv = jnp.full((E,), 5, jnp.int32)
+    ru = np.zeros((E, k), np.int8)
+    rv = np.zeros((E, k), np.int8)
+    # rows 0-7: all partitions tie; row i >= 8: u on partitions i-8 and
+    # k-1, v on neither, so the two replicas tie
+    for i in range(8, E):
+        ru[i, [i - 8, k - 1]] = 1
+    sz = jnp.full((k,), 7, jnp.int32)
+    c_k, _ = hdrf_choose(du, dv, jnp.asarray(ru), jnp.asarray(rv), sz,
+                         interpret=True)
+    c_r, _ = hdrf_choose_ref(du, dv, jnp.asarray(ru), jnp.asarray(rv), sz)
+    expect = np.r_[np.zeros(8, np.int32), np.arange(8, dtype=np.int32)]
+    np.testing.assert_array_equal(np.asarray(c_r), expect)
+    np.testing.assert_array_equal(np.asarray(c_k), expect)
+
+
 # ---------------------------------------------------------------------------
 # flash_attention (GQA, causal, decode, chunked prefill)
 # ---------------------------------------------------------------------------

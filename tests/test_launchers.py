@@ -13,7 +13,8 @@ def _run(args, timeout=420):
     return subprocess.run([sys.executable, "-m", *args],
                           capture_output=True, text=True, timeout=timeout,
                           env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                               "HOME": os.environ.get("HOME", "/root")})
+                               "HOME": os.environ.get("HOME", "/root"),
+                               "JAX_PLATFORMS": "cpu"})
 
 
 def test_train_cli_with_injected_failure(tmp_path):
@@ -143,3 +144,36 @@ def test_partition_cli_throttled(tmp_path):
     assert r.returncode == 0, r.stderr[-2000:]
     rep = json.loads(r.stdout)
     assert rep["simulated_io_s"] > 0
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    import jax
+    from repro import compile_cache
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = compile_cache.enable()
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        assert path == os.path.join(checkout, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_keeps_the_env_dir(monkeypatch, tmp_path):
+    import jax
+    from repro import compile_cache
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_dist_partition_fs_spawn_refuses_off_cpu(monkeypatch):
+    """Every spawned rank would start JAX on this host and claim the same
+    accelerator: without a CPU pin the parent refuses to spawn."""
+    from repro.launch import dist_partition
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit, match="same accelerator"):
+        dist_partition._spawn_fs_workers(None, [])

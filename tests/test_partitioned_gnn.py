@@ -182,7 +182,8 @@ def test_partitioned_gin_matches_dense_reference(quantile):
     """quantile=0.5 forces the psum-overflow exchange path too."""
     r = subprocess.run([sys.executable, "-c", _SPMD, quantile],
                        capture_output=True, text=True, timeout=420,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert "HALO_OK" in r.stdout, (r.stdout[-800:], r.stderr[-3000:])
 
 
@@ -282,7 +283,8 @@ def test_partitioned_gatedgcn_matches_dense_reference():
     distributed loss must equal the dense no-BN reference."""
     r = subprocess.run([sys.executable, "-c", _SPMD_GATEDGCN],
                        capture_output=True, text=True, timeout=420,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert "GATED_HALO_OK" in r.stdout, (r.stdout[-800:], r.stderr[-3000:])
 
 
@@ -344,7 +346,6 @@ _SPMD_EGNN = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import functools
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core import InMemoryEdgeStream, run_spec, spec_for
     from repro.dist.multihost import split_mesh_axes
@@ -429,11 +430,11 @@ _SPMD_EGNN = textwrap.dedent("""
     body = functools.partial(partitioned_egnn_forward, cfg, axes=axes,
                              v_cap=plan.v_cap)
     ps = P(("host", "device"))
-    fwd = shard_map(lambda pr, b: tuple(t[None] for t in body(pr, b)),
+    fwd = jax.shard_map(lambda pr, b: tuple(t[None] for t in body(pr, b)),
                     mesh=mesh,
                     in_specs=(jax.tree.map(lambda _: P(), params),
                               jax.tree.map(lambda _: ps, batch)),
-                    out_specs=(ps, ps), check_rep=False)
+                    out_specs=(ps, ps), check_vma=False)
     with mesh:
         h_all, x_all = jax.jit(fwd)(params, batch)
     h_all, x_all = np.asarray(h_all), np.asarray(x_all)
@@ -453,7 +454,8 @@ def test_partitioned_egnn_matches_dense_reference():
     the dense single-process EGNN within fp32 tolerance."""
     r = subprocess.run([sys.executable, "-c", _SPMD_EGNN],
                        capture_output=True, text=True, timeout=420,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert "EGNN_HALO_OK" in r.stdout, (r.stdout[-800:], r.stderr[-3000:])
 
 
@@ -552,6 +554,7 @@ def test_partitioned_gin_hostgrouped_matches_dense():
     reference."""
     r = subprocess.run([sys.executable, "-c", _SPMD_HOSTGROUPED],
                        capture_output=True, text=True, timeout=420,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert "HOSTGROUP_HALO_OK" in r.stdout, (r.stdout[-800:],
                                              r.stderr[-3000:])

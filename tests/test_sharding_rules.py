@@ -125,7 +125,7 @@ _SPMD_SCRIPT = textwrap.dedent("""
     b_sh = {k: NamedSharding(mesh, P("data", None)) for k in batch}
     batch = {k: jax.device_put(v, b_sh[k]) for k, v in batch.items()}
     step = jax.jit(S.make_lm_train_step(cfg), in_shardings=(st_sh, b_sh))
-    with mesh:
+    with jax.set_mesh(mesh):
         state2, metrics = step(state, batch)
     loss_spmd = float(metrics["loss"])
     # single-device reference
@@ -144,5 +144,6 @@ def test_spmd_train_step_matches_single_device():
     main test process keeps its 1-device view)."""
     r = subprocess.run([sys.executable, "-c", _SPMD_SCRIPT],
                        capture_output=True, text=True, timeout=300,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert "SPMD_OK" in r.stdout, r.stderr[-2000:]
