@@ -67,9 +67,10 @@ import json, sys
 from repro.obs import STAGES, validate_chrome_trace
 doc = json.load(open(sys.argv[1] + "/trace.json"))
 names = validate_chrome_trace(doc)
-missing = {"read", "dispatch", "writeback"} - names
+missing = {"engine.read", "engine.dispatch", "engine.device_wait",
+           "engine.fetch", "engine.write"} - names
 assert not missing, f"trace lacks pipeline-stage spans: {missing}"
-assert any(n.startswith("pass:") for n in names), names
+assert any(n.startswith("engine.pass.") for n in names), names
 manifest = json.load(open(sys.argv[1] + "/artifact/manifest.json"))
 stall = manifest["stall_report"]
 assert stall["critical_stage"] in STAGES, stall["critical_stage"]

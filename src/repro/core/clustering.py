@@ -35,6 +35,9 @@ class ClusteringResult:
     vol: np.ndarray        # (V,) cluster volumes (indexed by cluster id)
     degrees: np.ndarray    # (V,) true vertex degrees
     max_vol: int
+    moves: int = 0            # vertices moved, all passes
+    active_batches: int = 0   # micro-batches that moved a vertex
+    batches: int = 0          # micro-batches run, padding included
 
     @property
     def num_clusters(self) -> int:
@@ -125,7 +128,8 @@ def _cluster_chunk_step(v2c: jnp.ndarray, vol: jnp.ndarray, d: jnp.ndarray,
     """One host-dispatched chunk = ``lax.scan`` over ``sub``-edge micro
     batches.  The micro-batch keeps bulk-synchronous staleness negligible
     (measured: RF within noise of the sequential oracle) while amortizing
-    dispatch over the whole chunk."""
+    dispatch over the whole chunk.  The third result is the chunk's counts,
+    int32 ``(2,)``: vertices moved, and micro-batches that moved one."""
     C = edges.shape[0]
     assert C % sub == 0, (C, sub)
     edges_s = edges.reshape(C // sub, sub, 2)
@@ -138,7 +142,7 @@ def _cluster_chunk_step(v2c: jnp.ndarray, vol: jnp.ndarray, d: jnp.ndarray,
         return (v2c, vol), moved
 
     (v2c, vol), moved = jax.lax.scan(body, (v2c, vol), (edges_s, valid_s))
-    return v2c, vol, moved.sum()
+    return v2c, vol, jnp.stack([moved.sum(), (moved > 0).sum()])
 
 
 def streaming_clustering(stream: EdgeStream, degrees: np.ndarray | None = None,
@@ -150,7 +154,9 @@ def streaming_clustering(stream: EdgeStream, degrees: np.ndarray | None = None,
 
     ``readahead > 0`` reads chunks ahead on a background thread (the device
     dispatch here is already asynchronous — nothing below synchronizes per
-    chunk — so prefetching the host read is the only missing overlap)."""
+    chunk — so prefetching the host read is the only missing overlap).
+    Each pass counts its moves and active micro-batches on the device;
+    the totals are read with the final tables."""
     if degrees is None:
         degrees = compute_degrees(stream, chunk_size)
     if max_vol is None:
@@ -164,7 +170,9 @@ def streaming_clustering(stream: EdgeStream, degrees: np.ndarray | None = None,
     # does not invalidate ``d`` (astype to same dtype aliases the buffer).
     vol = jnp.array(degrees, jnp.int32, copy=True)
 
+    pass_counts, batches = [], 0
     for _ in range(passes):
+        counts = jnp.zeros((2,), jnp.int32)
         it = stream.iter_chunks_prefetch(chunk_size, readahead)
         try:
             for chunk in it:
@@ -173,16 +181,22 @@ def streaming_clustering(stream: EdgeStream, degrees: np.ndarray | None = None,
                     pad = np.zeros((chunk_size - n, 2), np.int32)
                     chunk = np.concatenate([chunk, pad], axis=0)
                 valid = jnp.arange(chunk_size) < n
-                v2c, vol, _ = _cluster_chunk_step(
+                v2c, vol, chunk_counts = _cluster_chunk_step(
                     v2c, vol, d, jnp.asarray(chunk), valid,
                     max_vol=int(max_vol), sub=sub)
+                counts = counts + chunk_counts      # stays on the device
+                batches += chunk_size // sub
         finally:
             if hasattr(it, "close"):
                 it.close()          # joins the prefetch thread on error
+        pass_counts.append(counts)
 
+    moves, active = sum((np.asarray(c, np.int64) for c in pass_counts),
+                        np.zeros(2, np.int64))
     return ClusteringResult(v2c=np.asarray(v2c), vol=np.asarray(vol),
                             degrees=np.asarray(degrees, np.int32),
-                            max_vol=int(max_vol))
+                            max_vol=int(max_vol), moves=int(moves),
+                            active_batches=int(active), batches=batches)
 
 
 def cluster_in_memory_scan(edges: jnp.ndarray, degrees: jnp.ndarray,

@@ -26,6 +26,12 @@ Each pass over the edge stream is a three-stage pipeline with up to
   runs once the deque exceeds the pipeline depth — i.e. chunk k's
   writeback overlaps chunk k+1's read and dispatch.
 
+Every stage of every chunk runs inside one ``repro.obs`` span
+(``engine.read``, ``engine.queue_wait``, ``engine.dispatch``, then
+``engine.device_wait``, ``engine.fetch``, ``engine.write``,
+``engine.fold``), which annotates the profiler's trace and credits the
+pass's ``StallClock``: the run's stage seconds.
+
 Depth 1 degenerates to the fully synchronous engine (dispatch, then
 immediately materialize).  **Any depth produces bit-identical
 assignments**: the chunk kernels execute in stream order with identical
@@ -92,9 +98,13 @@ class PartitionRunResult:
     """Everything ``run_spec`` produces for one partitioning run: the
     per-edge assignment (plain array, or the ``out_path`` memmap), the
     incrementally-maintained ``PartitionQuality``, per-phase wall-clock
-    ``timings``, and algorithm ``extras`` (2PS-L: pre-partition ratio,
-    cluster stats; any spec with ``host_groups``: ``num_hosts`` /
-    ``dcn_penalty`` / ``cross_host_rf``).  ``spec`` rides along so
+    ``timings``, each pass's chunk-stage seconds ``stages`` (``{phase:
+    {stage: seconds}}``, stages as ``repro.obs.CHUNK_STAGES``; the
+    writeback stages ``fetch`` + ``write`` + ``fold`` sum to
+    ``timings['writeback']``), and algorithm ``extras`` (2PS-L:
+    pre-partition ratio, cluster stats and counters; any spec with
+    ``host_groups``: ``num_hosts`` / ``dcn_penalty`` /
+    ``cross_host_rf``).  ``spec`` rides along so
     ``PartitionArtifact.save`` can embed the exact configuration."""
 
     name: str
@@ -103,6 +113,7 @@ class PartitionRunResult:
     assignment: np.ndarray                 # (E,) int32 edge -> partition
     quality: PartitionQuality
     timings: dict = field(default_factory=dict)   # phase -> seconds
+    stages: dict = field(default_factory=dict)    # phase -> stage -> s
     extras: dict = field(default_factory=dict)
     simulated_io_seconds: float = 0.0
     spec: PartitionerSpec | None = None
@@ -280,18 +291,17 @@ def compute_degrees_streaming(stream: EdgeStream, chunk_size: int, *,
     the host only prefetches + pads chunks while an O(|V|) device counter
     absorbs scatter-adds asynchronously.  Bit-identical to the host
     ``stream.compute_degrees`` sweep."""
-    tracer = get_tracer()
-    deg = jnp.zeros((stream.num_vertices,), jnp.int32)
-    it = stream.iter_chunks_prefetch(chunk_size, readahead)
-    try:
-        with tracer.span("pass:degrees", cat="engine"):
+    with get_tracer().span("engine.degrees", cat="engine"):
+        deg = jnp.zeros((stream.num_vertices,), jnp.int32)
+        it = stream.iter_chunks_prefetch(chunk_size, readahead)
+        try:
             for chunk in it:
                 pc = P.pad_chunk(chunk, chunk_size)
                 deg = _degree_fold(deg, pc.edges, pc.valid)
-    finally:
-        if hasattr(it, "close"):
-            it.close()              # joins the prefetch thread on error
-    return np.asarray(deg)
+        finally:
+            if hasattr(it, "close"):
+                it.close()          # joins the prefetch thread on error
+        return np.asarray(deg)
 
 
 @dataclass
@@ -477,7 +487,7 @@ class _TwoPSLPartitioner(StreamingPartitioner):
             degrees = compute_degrees_streaming(
                 stream, sp.chunk_size, readahead=sp.pipeline_depth - 1)
         timer.lap("degrees")
-        with get_tracer().span("pass:clustering", cat="engine",
+        with get_tracer().span("engine.clustering", cat="engine",
                                passes=sp.cluster_passes):
             clus = streaming_clustering(stream, degrees, k=k,
                                         max_vol_factor=sp.max_vol_factor,
@@ -485,7 +495,7 @@ class _TwoPSLPartitioner(StreamingPartitioner):
                                         chunk_size=sp.chunk_size,
                                         readahead=sp.pipeline_depth - 1)
         timer.lap("clustering")
-        with get_tracer().span("mapping", cat="engine"):
+        with get_tracer().span("engine.mapping", cat="engine"):
             # host-aware LPT only when the penalty is live: host_groups
             # alone (or dcn_penalty=0) must stay bit-identical to flat
             c2p, part_vol = map_clusters_lpt(
@@ -523,6 +533,9 @@ class _TwoPSLPartitioner(StreamingPartitioner):
              "clus_v2c": self._clus.v2c, "clus_vol": self._clus.vol,
              "clus_degrees": self._clus.degrees,
              "clus_max_vol": np.asarray(self._clus.max_vol),
+             "clus_counts": np.asarray([self._clus.moves,
+                                        self._clus.active_batches,
+                                        self._clus.batches], np.int64),
              "part_vol": np.asarray(self._part_vol)}
         if self._track_hbits:
             d["hbits"] = self._hbits_np
@@ -533,10 +546,12 @@ class _TwoPSLPartitioner(StreamingPartitioner):
         self._bits_np = np.ascontiguousarray(arrays["bits"])
         if self._track_hbits:
             self._hbits_np = np.ascontiguousarray(arrays["hbits"])
+        moves, active, batches = (int(c) for c in arrays["clus_counts"])
         self._clus = ClusteringResult(
             v2c=arrays["clus_v2c"], vol=arrays["clus_vol"],
             degrees=arrays["clus_degrees"],
-            max_vol=int(arrays["clus_max_vol"]))
+            max_vol=int(arrays["clus_max_vol"]), moves=moves,
+            active_batches=active, batches=batches)
         self._part_vol = arrays["part_vol"]
 
     def init_for_resume(self, stream, k, timer):
@@ -558,7 +573,7 @@ class _TwoPSLPartitioner(StreamingPartitioner):
                 "c2p": "constant", "host_of": "constant",
                 "clus_v2c": "constant", "clus_vol": "constant",
                 "clus_degrees": "constant", "clus_max_vol": "constant",
-                "part_vol": "constant"}
+                "clus_counts": "constant", "part_vol": "constant"}
 
     def _prepartition(self, st, pc):
         sizes, asg, _ = P._prepartition_core(
@@ -613,6 +628,9 @@ class _TwoPSLPartitioner(StreamingPartitioner):
             "num_clusters": self._clus.num_clusters,
             "max_vol": self._clus.max_vol,
             "cluster_passes": self.spec.cluster_passes,
+            "cluster_moves": self._clus.moves,
+            "cluster_active_batches": self._clus.active_batches,
+            "cluster_batches": self._clus.batches,
             "part_volumes": np.asarray(self._part_vol),
         }
         return state["bits"], state["sizes"], extras
@@ -797,25 +815,21 @@ def build_partitioner(spec: PartitionerSpec) -> StreamingPartitioner:
 # the one driver
 # ---------------------------------------------------------------------------
 
-def _traced_chunks(it, tracer, stall, start=0):
-    """Wrap the raw chunk iterator so each read/decode is credited to the
-    prefetch stage *on whatever thread runs it* (the prefetch thread at
-    depth >= 2, inline on the main thread at depth 1)."""
-    i = start
-    while True:
-        t0 = time.perf_counter()
-        try:
-            chunk = next(it)
-        except StopIteration:
-            return
-        dt = time.perf_counter() - t0
-        tracer.complete("read", "prefetch", dt, chunk=i)
-        stall.add("prefetch", dt)
-        yield chunk
-        i += 1
-
-
 _STREAM_END = object()
+
+
+def _traced_chunks(it, tracer, stall, start=0):
+    """Wrap the raw chunk iterator so each read/decode is an
+    ``engine.read`` span *on whatever thread runs it* (the prefetch thread
+    at depth >= 2, inline on the main thread at depth 1).  The last span
+    is the read that finds the stream's end."""
+    for i in itertools.count(start):
+        with tracer.span("engine.read", cat="prefetch", stall=stall,
+                         chunk=i):
+            chunk = next(it, _STREAM_END)
+        if chunk is _STREAM_END:
+            return
+        yield chunk
 
 
 @dataclass
@@ -826,8 +840,8 @@ class _PassResult:
     assigned: int      # rows this sweep assigned (pass-count delta)
     lo: int            # next assignment row
     next_chunk: int    # next chunk index
-    wb_host: float     # host-side writeback seconds
-    ckpt_host: float   # checkpoint-save seconds (drain included)
+    wb_host: float     # host-side writeback seconds: fetch + write + fold
+    ckpt_host: float   # checkpoint-save seconds (drain's writeback not)
 
 
 def _run_pass_pipeline(sp, state, stream, *, eff_chunk, depth, tracer,
@@ -855,41 +869,40 @@ def _run_pass_pipeline(sp, state, stream, *, eff_chunk, depth, tracer,
     inflight_gauge = metrics.gauge("engine.chunks_in_flight")
     edges_ctr = metrics.counter("engine.edges_streamed")
     chunks_ctr = metrics.counter("engine.chunks_total")
-    dispatch_hist = metrics.histogram("engine.dispatch_seconds")
-    writeback_hist = metrics.histogram("engine.writeback_seconds")
 
     def _writeback():
         nonlocal assigned, wb_host
         w_lo, w_chunk, w_n, w_asg, w_i = inflight.popleft()
-        t0 = time.perf_counter()
-        w_asg = jax.block_until_ready(w_asg)
-        t1 = time.perf_counter()
-        asg_np = np.asarray(w_asg)[:w_n]
-        assigned += write_rows(w_lo, w_n, asg_np, sp.merge)
+        with tracer.span("engine.device_wait", cat="writeback", stall=stall,
+                         chunk=w_i):
+            w_asg = jax.block_until_ready(w_asg)
+        with tracer.span("engine.fetch", cat="writeback", stall=stall,
+                         chunk=w_i) as fetch:
+            asg_np = np.asarray(w_asg)[:w_n]
+        with tracer.span("engine.write", cat="writeback", stall=stall,
+                         chunk=w_i) as write:
+            assigned += write_rows(w_lo, w_n, asg_np, sp.merge)
+        wb_host += fetch.seconds + write.seconds
         if sp.host_fold is not None:
-            sp.host_fold(w_chunk, asg_np)
-        t2 = time.perf_counter()
-        tracer.complete("device_wait", "writeback", t1 - t0, chunk=w_i)
-        tracer.complete("writeback", "writeback", t2 - t1, chunk=w_i)
-        stall.add("writeback", t2 - t0)
-        stall.attribute("device_wait", t1 - t0)
-        stall.attribute("host_write", t2 - t1)
-        writeback_hist.observe(t2 - t0)
-        wb_host += t2 - t1
+            with tracer.span("engine.fold", cat="writeback", stall=stall,
+                             chunk=w_i) as fold:
+                sp.host_fold(w_chunk, asg_np)
+            wb_host += fold.seconds
 
     def _save_checkpoint(next_chunk):
         nonlocal ckpt_host
-        t0 = time.perf_counter()
-        # consistency barrier: drain the pipeline so state, the
-        # assignment rows below ``lo``, and the cursor all agree
-        while inflight:
-            _writeback()
-        jax.block_until_ready(state)
-        save_state(int(next_chunk), state, lo, assigned)
-        dt = time.perf_counter() - t0
-        ckpt_host += dt
-        tracer.complete("checkpoint", "robust", dt, pass_index=pass_index,
-                        next_chunk=int(next_chunk))
+        wb0 = wb_host
+        with tracer.span("engine.checkpoint", cat="robust",
+                         pass_index=pass_index,
+                         next_chunk=int(next_chunk)) as span:
+            # consistency barrier: drain the pipeline so state, the
+            # assignment rows below ``lo``, and the cursor all agree
+            while inflight:
+                _writeback()
+            jax.block_until_ready(state)
+            save_state(int(next_chunk), state, lo, assigned)
+        # the drain's writeback is already in wb_host
+        ckpt_host += span.seconds - (wb_host - wb0)
         metrics.counter("engine.checkpoints").inc()
 
     # wrap the raw iterator (prefetch-stage attribution in the producer
@@ -902,23 +915,18 @@ def _run_pass_pipeline(sp, state, stream, *, eff_chunk, depth, tracer,
                   readahead=depth - 1)
     ci = first_chunk
     try:
-        with tracer.span(f"pass:{sp.phase}", cat="engine",
+        with tracer.span(f"engine.pass.{sp.phase}", cat="engine",
                          depth=depth, merge=sp.merge):
             while True:
-                tq = time.perf_counter()
-                chunk = next(it, _STREAM_END)
-                wait = time.perf_counter() - tq
-                tracer.complete("queue_wait", "dispatch", wait, chunk=ci)
-                stall.attribute("queue_wait", wait)
+                with tracer.span("engine.queue_wait", cat="dispatch",
+                                 stall=stall, chunk=ci):
+                    chunk = next(it, _STREAM_END)
                 if chunk is _STREAM_END:
                     break
-                td = time.perf_counter()
-                pc = P.pad_chunk(chunk, eff_chunk)
-                state, asg = sp.chunk_fn(state, pc)
-                dt = time.perf_counter() - td
-                tracer.complete("dispatch", "dispatch", dt, chunk=ci)
-                stall.add("dispatch", dt)
-                dispatch_hist.observe(dt)
+                with tracer.span("engine.dispatch", cat="dispatch",
+                                 stall=stall, chunk=ci):
+                    pc = P.pad_chunk(chunk, eff_chunk)
+                    state, asg = sp.chunk_fn(state, pc)
                 inflight.append((lo, chunk, pc.n, asg, ci))
                 inflight_gauge.set(len(inflight))
                 edges_ctr.inc(pc.n)
@@ -932,12 +940,9 @@ def _run_pass_pipeline(sp, state, stream, *, eff_chunk, depth, tracer,
                     _save_checkpoint(ci)
             while inflight:
                 _writeback()
-            tdr = time.perf_counter()
-            jax.block_until_ready(state)
-            drain = time.perf_counter() - tdr
-            tracer.complete("device_wait", "writeback", drain,
-                            drain=True)
-            stall.attribute("device_wait", drain)
+            with tracer.span("engine.device_wait", cat="writeback",
+                             stall=stall, drain=True):
+                jax.block_until_ready(state)
     finally:
         if hasattr(it, "close"):
             it.close()              # joins the prefetch thread on error
@@ -966,12 +971,16 @@ def run_spec(spec: PartitionerSpec, stream: EdgeStream, k: int, *,
     next to the flat ``PartitionQuality``; a nonzero ``dcn_penalty``
     additionally steers the scoring passes themselves (stateful specs).
 
-    ``tracer`` (``repro.obs.Tracer``) records per-chunk spans for every
-    pipeline stage (``read`` / ``queue_wait`` / ``dispatch`` /
-    ``device_wait`` / ``writeback`` plus the ``pass:*`` envelopes) and
+    Every pipeline stage of every chunk is a ``repro.obs`` span
+    (``engine.read`` / ``queue_wait`` / ``dispatch`` / ``device_wait`` /
+    ``fetch`` / ``write`` / ``fold``, inside the ``engine.pass.<phase>``
+    envelopes; ``repro.obs.SPANS``), seen by any open profiler session
+    and summed per pass into ``stages`` on every run.  ``tracer``
+    (``repro.obs.Tracer``) additionally records the spans in memory and
     attaches the ``PipelineStallReport`` as
     ``extras['stall_report']``; ``metrics`` (``repro.obs.MetricsRegistry``)
-    accumulates edges/sec, chunks in flight, and replication-state bytes.
+    accumulates edges streamed, chunks in flight, and replication-state
+    bytes.
     Both default to the process-global active instances (``use_tracer`` /
     ``use_registry``), which are no-ops unless a caller activated them —
     and a traced run is **bit-identical** to an untraced run: tracing only
@@ -984,6 +993,8 @@ def run_spec(spec: PartitionerSpec, stream: EdgeStream, k: int, *,
         res.quality.replication_factor   # the paper's RF
         res.timings                      # {'degrees': ..., 'scoring': ...,
                                          #  'writeback': ..., 'finalize': ...}
+        res.stages                       # {'prepartition': {'read': ...,
+                                         #  'dispatch': ..., 'fold': ...}, ...}
 
     Robustness (``repro.robust``, guide: docs/robustness.md):
 
@@ -1032,7 +1043,8 @@ def _run_spec_traced(spec, stream, k, out_path, degrees, tracer, metrics,
         if ckpt is not None:
             _ck.check_compatible(ckpt.meta, spec, stream, k, out_path)
     if ckpt is not None:
-        with tracer.span("resume", cat="engine", algorithm=spec.algorithm,
+        with tracer.span("engine.resume", cat="engine",
+                         algorithm=spec.algorithm,
                          pass_index=int(ckpt.meta["pass_index"]),
                          next_chunk=int(ckpt.meta["next_chunk"])):
             part.init_for_resume(stream, k, timer)
@@ -1049,12 +1061,11 @@ def _run_spec_traced(spec, stream, k, out_path, degrees, tracer, metrics,
         # gauge advertises — a resumed process must not report 0
         _set_replication_gauge(part, state, metrics)
     else:
-        with tracer.span("init", cat="engine", algorithm=spec.algorithm,
-                         k=k):
+        with tracer.span("engine.init", cat="engine",
+                         algorithm=spec.algorithm, k=k):
             state = part.init_state(stream, k, timer, degrees)
         assignment = _alloc_assignment(stream.num_edges, out_path)
     depth = spec.pipeline_depth
-    edges_ctr = metrics.counter("engine.edges_streamed")
 
     resumes = int(ckpt.meta["resumes"]) + 1 if ckpt is not None else 0
     checkpoints_written = 0
@@ -1063,7 +1074,6 @@ def _run_spec_traced(spec, stream, k, out_path, degrees, tracer, metrics,
         {kk: int(v) for kk, v in ckpt.meta["pass_counts"].items()}
         if ckpt is not None else {})
     pass_stalls = []
-    passes_wall = 0.0
     write_rows = _assignment_writer(assignment)
     for pi, sp in enumerate(part.passes()):
         if pi < start_pass:
@@ -1072,7 +1082,7 @@ def _run_spec_traced(spec, stream, k, out_path, degrees, tracer, metrics,
         # the checkpointed device state is post-setup for the pass in
         # flight, so setup must not run again on resume
         if sp.setup is not None and not resuming_here:
-            with tracer.span("setup", cat="engine", phase=sp.phase):
+            with tracer.span("engine.setup", cat="engine", phase=sp.phase):
                 state = sp.setup(state)
         stall = StallClock()
 
@@ -1128,11 +1138,9 @@ def _run_spec_traced(spec, stream, k, out_path, degrees, tracer, metrics,
         if pr.ckpt_host:
             timer.add("checkpoint", pr.ckpt_host)
         pass_counts[sp.phase] = pass_counts.get(sp.phase, 0) + pr.assigned
-        ps = stall.report(sp.phase)
-        pass_stalls.append(ps)
-        passes_wall += ps.wall_seconds
+        pass_stalls.append(stall.report(sp.phase))
 
-    with tracer.span("finalize", cat="engine"):
+    with tracer.span("engine.finalize", cat="engine"):
         bits, sizes, extras = part.finalize(state, pass_counts)
         sizes_np = np.asarray(sizes)
         bits_np = np.asarray(bits)
@@ -1142,12 +1150,9 @@ def _run_spec_traced(spec, stream, k, out_path, degrees, tracer, metrics,
     resident = part.replication_state_bytes()
     metrics.gauge("engine.replication_state_bytes").set(
         bits_np.nbytes if resident is None else int(resident))
-    if passes_wall > 0:
-        metrics.gauge("engine.edges_per_sec").set(
-            edges_ctr.value / passes_wall if metrics.enabled else 0.0)
+    stalls = PipelineStallReport(passes=pass_stalls)
     if tracer.enabled:
-        extras["stall_report"] = PipelineStallReport(
-            passes=pass_stalls).to_dict()
+        extras["stall_report"] = stalls.to_dict()
     if resumes:
         extras["resumes"] = resumes
     if ckpt_every:
@@ -1167,5 +1172,5 @@ def _run_spec_traced(spec, stream, k, out_path, degrees, tracer, metrics,
     return PartitionRunResult(
         name=part.display_name, k=k, alpha=spec.alpha,
         assignment=assignment, quality=quality, timings=timer.t,
-        extras=extras, simulated_io_seconds=stream.simulated_io_seconds,
-        spec=spec)
+        stages=stalls.stage_seconds(), extras=extras,
+        simulated_io_seconds=stream.simulated_io_seconds, spec=spec)

@@ -44,12 +44,15 @@ every N chunks (``--checkpoint-dir`` defaults to
 checkpoint into a bit-identical final assignment; ``--io-retries R``
 validates and retries chunk reads with bounded backoff.
 
-Observability (``repro.obs``, see docs/observability.md): ``--trace
-out.json`` records every pipeline stage, halo-planning step, and pass as
-Chrome ``trace_event`` spans (open in Perfetto), ``--trace-summary``
-prints the per-stage stall table, and ``--jax-profile DIR`` additionally
-captures a ``jax.profiler`` device trace.  Traced runs are bit-identical
-to untraced runs.
+Observability (``repro.obs``, see docs/observability.md): every report
+carries the engine's stage seconds per pass (``stages_s``) and, with
+``--artifact-dir``, the seconds the artifact took to save
+(``artifact_save_s``).  ``--trace out.json`` records every pipeline
+stage, halo-planning step, and pass as Chrome ``trace_event`` spans
+(open in Perfetto), ``--trace-summary`` prints the per-stage stall table,
+and ``--jax-profile DIR`` captures a ``jax.profiler`` device trace in
+which the same spans sit beside the device's operations.  Traced runs are
+bit-identical to untraced runs.
 """
 from __future__ import annotations
 
@@ -246,6 +249,8 @@ def main(argv=None):
             "replication_factor": res.quality.replication_factor,
             "alpha_measured": res.quality.balance,
             "timings_s": {k: round(v, 3) for k, v in res.timings.items()},
+            "stages_s": {phase: {st: round(v, 6) for st, v in sec.items()}
+                         for phase, sec in res.stages.items()},
             "simulated_io_s": round(res.simulated_io_seconds, 3),
             **{k: v for k, v in res.extras.items()
                if isinstance(v, (int, float, str))},
@@ -259,11 +264,14 @@ def main(argv=None):
                            MemmapEdgeStream(
                                args.input,
                                num_vertices=stream.num_vertices))
-            art = PartitionArtifact.save(
-                args.artifact_dir, res, num_vertices=stream.num_vertices,
-                num_edges=stream.num_edges, stream=plan_stream,
-                pair_cap_quantile=args.pair_cap_quantile,
-                host_groups=args.hosts, graph_path=args.input)
+            with tracer.span("artifact.save", cat="artifact") as save:
+                art = PartitionArtifact.save(
+                    args.artifact_dir, res,
+                    num_vertices=stream.num_vertices,
+                    num_edges=stream.num_edges, stream=plan_stream,
+                    pair_cap_quantile=args.pair_cap_quantile,
+                    host_groups=args.hosts, graph_path=args.input)
+            report["artifact_save_s"] = round(save.seconds, 6)
             report["artifact_dir"] = args.artifact_dir
             if args.local_graphs:
                 from repro.sample import build_local_graphs

@@ -17,8 +17,8 @@ __all__ = ["chrome_trace", "write_chrome_trace", "validate_chrome_trace",
            "trace_summary_table", "jax_profiler_session",
            "TraceValidationError"]
 
-#: Event phases the tracer emits (complete, counter, instant, metadata).
-_KNOWN_PHASES = frozenset("XCiM")
+#: Event phases the tracer emits (complete, instant, metadata).
+_KNOWN_PHASES = frozenset("XiM")
 
 
 class TraceValidationError(ValueError):
@@ -100,7 +100,7 @@ def trace_summary_table(report, metrics_snapshot: dict | None = None) -> str:
                      f"critical={p['critical_stage']}"
                      + (f"  [{attr}]" if attr else ""))
     if metrics_snapshot:
-        for name in ("engine.edges_per_sec", "engine.chunks_in_flight",
+        for name in ("engine.chunks_in_flight",
                      "engine.replication_state_bytes",
                      "halo.dcn_rows_aggregated", "halo.intra_rows"):
             m = metrics_snapshot.get(name)
@@ -115,10 +115,11 @@ def trace_summary_table(report, metrics_snapshot: dict | None = None) -> str:
 @contextlib.contextmanager
 def jax_profiler_session(log_dir: str | None):
     """Capture a ``jax.profiler`` device trace around the block
-    (TensorBoard/XProf format, complements the host-side span trace: the
+    (TensorBoard/XProf format): every ``repro.obs`` span appears there on
+    the host thread that ran it, beside the device's operations, and the
     ``jax.named_scope`` annotations in ``_halo_combine`` and the chunk
-    kernels show up there).  ``log_dir=None`` is a plain pass-through; a
-    profiler that cannot start raises."""
+    kernels name device operations.  ``log_dir=None`` is a plain
+    pass-through; a profiler that cannot start raises."""
     if not log_dir:
         yield False
         return

@@ -1,19 +1,16 @@
 """Metrics registry: counters, gauges, histograms with snapshot export.
 
 The partitioning/halo stack records its operational numbers here —
-edges/sec, chunks in flight, replication-state bytes, DCN vs ICI lane
-rows — so launchers and benchmarks can export one JSON-safe snapshot
+edges streamed, chunks in flight, replication-state bytes, DCN vs ICI
+lane rows — so launchers and benchmarks can export one JSON-safe snapshot
 instead of scraping prints.  Canonical instrument names:
 
     engine.edges_streamed        counter  edges entering the pipeline
     engine.chunks_total          counter  chunks dispatched (all passes)
     engine.chunks_in_flight      gauge    deque occupancy (high-water in
                                           ``max``)
-    engine.edges_per_sec         gauge    streamed edges / pass wall time
     engine.replication_state_bytes
                                  gauge    final replication bit-matrix size
-    engine.dispatch_seconds      histogram  per-chunk host dispatch time
-    engine.writeback_seconds     histogram  per-chunk writeback time
     engine.io_retries            counter  chunk reads recovered by the
                                           retrying stream (repro.robust)
     engine.checkpoints           counter  engine checkpoints written

@@ -3,16 +3,25 @@
 The engine overlaps read (prefetch thread), device dispatch, and
 writeback; ROADMAP decisions like "grow ``pipeline_depth`` until
 writeback stops stalling" need to know which stage the stream actually
-waits on.  A ``StallClock`` accumulates, per pass over the edge stream:
+waits on.  A ``StallClock`` accumulates, per pass over the edge stream,
+the seconds of each chunk stage (``CHUNK_STAGES``), credited by the
+engine's ``engine.<stage>`` spans on whatever thread runs them:
 
-* per-stage **busy** time — ``prefetch`` (producer-side chunk read /
-  decode, measured on whatever thread runs it), ``dispatch`` (pad +
-  ``chunk_fn`` host time), ``writeback`` (device wait + host
-  materialization + memmap writes + host folds);
-* finer **attribution** buckets — ``queue_wait`` (consumer blocked on
-  the prefetch queue), ``device_wait`` (``block_until_ready`` inside
-  writeback and the end-of-pass drain), ``host_write`` (writeback minus
-  device wait).
+* ``read`` — producer-side chunk read / decode (the prefetch thread at
+  depth >= 2);
+* ``queue_wait`` — the consumer blocked on the prefetch queue;
+* ``dispatch`` — pad + ``chunk_fn`` host time;
+* ``device_wait`` — ``block_until_ready`` on a chunk's assignment, and
+  the end-of-pass drain;
+* ``fetch`` — the device-to-host copy of a chunk's assignment;
+* ``write`` — the assignment rows written to their sink;
+* ``fold`` — the pass's host-side replica fold (``StreamPass.host_fold``).
+
+These are the run's stage seconds (``PartitionRunResult.stages``, the CLI
+report's ``stages_s``) and each pass's ``attribution``.  They roll up into
+the pipeline's three ``STAGES``: ``prefetch`` is busy reading,
+``dispatch`` dispatching, ``writeback`` waiting on the device, fetching,
+writing and folding.
 
 ``StallClock.report`` rolls one pass into a ``PassStall``; the run-level
 ``PipelineStallReport`` aggregates passes and renders the verdict.  For
@@ -27,10 +36,20 @@ import threading
 import time
 from dataclasses import dataclass, field
 
-__all__ = ["StallClock", "PassStall", "PipelineStallReport", "STAGES"]
+__all__ = ["StallClock", "PassStall", "PipelineStallReport", "STAGES",
+           "CHUNK_STAGES"]
 
 #: The engine's pipeline stages, in stream order.
 STAGES = ("prefetch", "dispatch", "writeback")
+
+#: The per-chunk stages a pass's spans time, in stream order.
+CHUNK_STAGES = ("read", "queue_wait", "dispatch", "device_wait", "fetch",
+                "write", "fold")
+
+#: pipeline stage -> the chunk stages it is busy in (its chunk count is
+#: the first one's)
+_BUSY = {"prefetch": ("read",), "dispatch": ("dispatch",),
+         "writeback": ("fetch", "device_wait", "write", "fold")}
 
 
 class StallClock:
@@ -38,30 +57,34 @@ class StallClock:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self.busy = {s: 0.0 for s in STAGES}
-        self.chunks = {s: 0 for s in STAGES}
-        self.attribution: dict[str, float] = {}
+        self.seconds: dict[str, float] = {}
+        self.chunks: dict[str, int] = {}
         self._t0 = time.perf_counter()
 
-    def add(self, stage: str, seconds: float):
-        """Credit ``seconds`` of busy time (one chunk) to ``stage``."""
+    def add(self, span: str, seconds: float):
+        """Credit ``seconds`` of one span to its stage: the part of the
+        span's name after the last dot (``engine.fold`` -> ``fold``)."""
+        stage = span.rpartition(".")[2]
         with self._lock:
-            self.busy[stage] = self.busy.get(stage, 0.0) + seconds
+            self.seconds[stage] = self.seconds.get(stage, 0.0) + seconds
             self.chunks[stage] = self.chunks.get(stage, 0) + 1
-
-    def attribute(self, bucket: str, seconds: float):
-        """Credit ``seconds`` to a fine-grained attribution bucket."""
-        with self._lock:
-            self.attribution[bucket] = (
-                self.attribution.get(bucket, 0.0) + seconds)
 
     def report(self, phase: str) -> "PassStall":
         """Close the pass: wall time is now - construction time."""
         wall = time.perf_counter() - self._t0
         with self._lock:
             return PassStall(phase=phase, wall_seconds=wall,
-                             busy=dict(self.busy), chunks=dict(self.chunks),
-                             attribution=dict(self.attribution))
+                             attribution=dict(self.seconds),
+                             chunks=dict(self.chunks))
+
+
+def _busy(attribution: dict, chunks: dict) -> tuple[dict, dict]:
+    """Chunk-stage seconds and counts -> pipeline-stage busy seconds and
+    chunk counts."""
+    busy = {s: sum(attribution.get(c, 0.0) for c in cs)
+            for s, cs in _BUSY.items()}
+    count = {s: chunks.get(cs[0], 0) for s, cs in _BUSY.items()}
+    return busy, count
 
 
 def _stage_fractions(busy: dict, chunks: dict, wall: float) -> dict:
@@ -86,12 +109,12 @@ class PassStall:
 
     phase: str
     wall_seconds: float
-    busy: dict = field(default_factory=dict)      # stage -> seconds
-    chunks: dict = field(default_factory=dict)    # stage -> chunk count
-    attribution: dict = field(default_factory=dict)
+    attribution: dict = field(default_factory=dict)  # chunk stage -> s
+    chunks: dict = field(default_factory=dict)  # chunk stage -> spans
 
     def stages(self) -> dict:
-        return _stage_fractions(self.busy, self.chunks, self.wall_seconds)
+        return _stage_fractions(*_busy(self.attribution, self.chunks),
+                                self.wall_seconds)
 
     def to_dict(self) -> dict:
         stages = self.stages()
@@ -99,6 +122,7 @@ class PassStall:
                 "wall_s": self.wall_seconds,
                 "stages": stages,
                 "attribution": dict(self.attribution),
+                "chunks": dict(self.chunks),
                 "critical_stage": _critical(stages)}
 
 
@@ -122,11 +146,21 @@ class PipelineStallReport:
         busy: dict[str, float] = {}
         chunks: dict[str, int] = {}
         for p in self.passes:
-            for s, v in p.busy.items():
+            for s, v in p.attribution.items():
                 busy[s] = busy.get(s, 0.0) + v
             for s, n in p.chunks.items():
                 chunks[s] = chunks.get(s, 0) + n
-        return _stage_fractions(busy, chunks, self.wall_seconds)
+        return _stage_fractions(*_busy(busy, chunks), self.wall_seconds)
+
+    def stage_seconds(self) -> dict:
+        """``{phase: {chunk stage: seconds}}``, passes of one phase
+        summed."""
+        out: dict[str, dict] = {}
+        for p in self.passes:
+            acc = out.setdefault(p.phase, {})
+            for s, v in p.attribution.items():
+                acc[s] = acc.get(s, 0.0) + v
+        return out
 
     @property
     def critical_stage(self) -> str:
@@ -150,10 +184,7 @@ class PipelineStallReport:
     @classmethod
     def from_dict(cls, d: dict) -> "PipelineStallReport":
         passes = [PassStall(phase=p["phase"], wall_seconds=p["wall_s"],
-                            busy={s: v["busy_s"]
-                                  for s, v in p["stages"].items()},
-                            chunks={s: v["chunks"]
-                                    for s, v in p["stages"].items()},
-                            attribution=dict(p.get("attribution", {})))
+                            attribution=dict(p["attribution"]),
+                            chunks=dict(p["chunks"]))
                   for p in d.get("passes", [])]
         return cls(passes=passes)

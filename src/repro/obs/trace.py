@@ -1,26 +1,32 @@
 """Span tracing for the streaming/partitioning stack.
 
-A ``Tracer`` records nested wall-clock spans from any thread — the
-engine's prefetch thread and main thread each get their own lane — as
-Chrome ``trace_event`` complete events (``ph: "X"``), so one run exports
-straight into Perfetto / ``chrome://tracing`` (see ``repro.obs.export``).
+One span feeds three sinks.  ``with tracer.span("engine.fold", stall=clk,
+chunk=i): ...``
 
-Two recording styles, both thread-safe:
+* always enters a ``jax.profiler.TraceAnnotation`` under the span's name,
+  so a profiler session (``--jax-profile``, the benchmark's device-trace
+  slices) shows the span on the host thread that ran it, beside the
+  device's operations; keyword arguments become the event's stats and the
+  event name stays constant;
+* credits its duration to the pass's ``StallClock`` when given one
+  (``stall=``), the accumulator behind every run's stage seconds;
+* appends a Chrome ``trace_event`` complete event (``ph: "X"``) only when
+  the tracer is enabled (``Tracer``), so one run exports straight into
+  Perfetto / ``chrome://tracing`` (see ``repro.obs.export``).
 
-* ``with tracer.span("dispatch", cat="engine", chunk=i): ...`` — a
-  context-managed span (begin on enter, complete event on exit).  Spans
-  opened and closed on the same thread nest correctly by construction.
-* ``tracer.complete("read", "prefetch", dt_seconds, chunk=i)`` — emit a
-  span retrospectively from an already-measured duration ending *now*.
-  This is what hot loops use: one timer read + one list append, no
-  context-manager overhead, and no spurious span when a generator is
-  abandoned mid-``next``.
+Every thread gets its own lane: the engine's prefetch thread and main
+thread each record where they run.  The span's measured seconds stay on
+the span object (``span.seconds``) after it exits.
 
-Disabled tracing is the ``NULL_TRACER`` singleton whose ``span`` returns
-one reusable no-op context manager and whose ``complete`` is a no-op —
-instrumentation points cost a couple of attribute lookups when tracing is
-off, and a traced run is bit-identical to an untraced one (tracing only
-*observes* the pipeline, never reorders it).
+Disabled tracing is the ``NULL_TRACER`` singleton: its spans still reach
+the profiler and the stall clock, and record nothing in memory.  With no
+profiler session a span costs one annotation (about 1 us on a CPU) and two
+``perf_counter`` reads.  A traced run is bit-identical to an untraced one
+(tracing only *observes* the pipeline, never reorders it).
+
+``SPANS`` names every span the engine and the partition CLI emit; the
+layer prefix (``engine.``, ``artifact.``) keeps them apart from JAX's own
+host events in a profiler trace.
 
 Instrumentation points that cannot thread a tracer argument through
 (e.g. halo planning called from inside ``PartitionArtifact.save``) use
@@ -41,41 +47,76 @@ import os
 import threading
 import time
 
-__all__ = ["Tracer", "NullTracer", "NULL_TRACER", "get_tracer",
-           "use_tracer"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "NullTracer", "NULL_TRACER", "PASS_PHASES", "SPANS",
+           "get_tracer", "use_tracer"]
+
+#: ``StreamPass.phase`` of every streaming partitioner's passes.
+PASS_PHASES = ("prepartition", "scoring", "hashing", "hybrid", "buffered")
+
+#: Every span name the engine and the partition CLI emit.  The per-chunk
+#: stages (``engine.read`` on the thread that reads, the rest on the main
+#: thread) share a ``chunk`` argument; ``StallClock`` keys each by the
+#: part after the last dot.
+SPANS = ("engine.init", "engine.resume", "engine.degrees",
+         "engine.clustering", "engine.mapping", "engine.setup",
+         *(f"engine.pass.{p}" for p in PASS_PHASES),
+         "engine.read", "engine.queue_wait", "engine.dispatch",
+         "engine.device_wait", "engine.fetch", "engine.write", "engine.fold",
+         "engine.checkpoint", "engine.finalize", "artifact.save")
 
 
-class _NullSpan:
-    __slots__ = ()
+class _Span:
+    """One timed region: profiler annotation, stall credit, and (when the
+    tracer is enabled) a Chrome complete event."""
+
+    __slots__ = ("_tracer", "name", "_cat", "_stall", "_args", "_ann",
+                 "_t0", "seconds")
+
+    def __init__(self, tracer, name, cat, stall, args):
+        self._tracer = tracer
+        self.name = name
+        self._cat = cat
+        self._stall = stall
+        self._args = args
+        self.seconds = 0.0
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self.name, **self._args)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self.seconds = t1 - self._t0
+        if self._stall is not None:
+            self._stall.add(self.name, self.seconds)
+        if self._tracer.enabled:
+            self._tracer._emit("X", self.name, self._cat,
+                               self._tracer._us(self._t0),
+                               dur=self.seconds * 1e6, args=self._args)
         return False
 
 
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
-    """No-op tracer: every instrumentation point degrades to a constant
-    attribute lookup.  ``enabled`` is the one flag consumers branch on
-    (e.g. the engine only attaches a stall report when it is True)."""
+    """Tracer that keeps no events: spans still annotate the profiler's
+    trace and credit a stall clock.  ``enabled`` is the one flag consumers
+    branch on (e.g. the engine only attaches a stall report when it is
+    True)."""
 
     enabled = False
     __slots__ = ()
 
-    def span(self, name, cat="", **args):
-        return _NULL_SPAN
+    def span(self, name, cat="", *, stall=None, **args):
+        return _Span(self, name, cat, stall, args)
 
     def complete(self, name, cat="", duration_s=0.0, **args):
         pass
 
     def instant(self, name, cat="", **args):
-        pass
-
-    def counter(self, name, value, series="value"):
         pass
 
     def events(self):
@@ -87,26 +128,6 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
-
-
-class _Span:
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
-
-    def __init__(self, tracer, name, cat, args):
-        self._tracer = tracer
-        self._name = name
-        self._cat = cat
-        self._args = args
-
-    def __enter__(self):
-        self._t0 = self._tracer._now_us()
-        return self
-
-    def __exit__(self, *exc):
-        t1 = self._tracer._now_us()
-        self._tracer._emit("X", self._name, self._cat, self._t0,
-                           dur=t1 - self._t0, args=self._args)
-        return False
 
 
 class Tracer:
@@ -130,8 +151,9 @@ class Tracer:
         self._t0 = time.perf_counter()
 
     # -- clock -----------------------------------------------------------
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    def _us(self, t: float) -> float:
+        """``perf_counter`` reading -> microseconds since the epoch."""
+        return (t - self._t0) * 1e6
 
     # -- recording -------------------------------------------------------
     def _emit(self, ph, name, cat, ts, *, dur=None, args=None):
@@ -156,26 +178,23 @@ class Tracer:
                 ev["args"] = args
             self._events.append(ev)
 
-    def span(self, name: str, cat: str = "", **args):
-        """Context manager recording one complete span."""
-        return _Span(self, name, cat, args)
+    def span(self, name: str, cat: str = "", *, stall=None, **args):
+        """Context manager timing one region (see the module docstring);
+        ``stall`` is the ``StallClock`` its seconds are credited to."""
+        return _Span(self, name, cat, stall, args)
 
     def complete(self, name: str, cat: str = "", duration_s: float = 0.0,
                  **args):
-        """Record a span of ``duration_s`` seconds that ends *now*.  The
+        """Record a span of ``duration_s`` seconds that ends *now*, for an
+        event measured elsewhere (it reaches no profiler trace).  The
         start is clamped to the tracer's epoch so a duration measured
         before the tracer existed still yields a valid (ts >= 0) event."""
-        now = self._now_us()
+        now = self._us(time.perf_counter())
         self._emit("X", name, cat, max(now - duration_s * 1e6, 0.0),
                    dur=duration_s * 1e6, args=args)
 
     def instant(self, name: str, cat: str = "", **args):
-        self._emit("i", name, cat, self._now_us(), args=args)
-
-    def counter(self, name: str, value, series: str = "value"):
-        """Record a counter sample (rendered as a track in Perfetto)."""
-        self._emit("C", name, "metrics", self._now_us(),
-                   args={series: float(value)})
+        self._emit("i", name, cat, self._us(time.perf_counter()), args=args)
 
     # -- export ----------------------------------------------------------
     def events(self) -> list[dict]:

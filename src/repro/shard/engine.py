@@ -49,7 +49,7 @@ from ..core.engine import (PartitionRunResult, StallClock, _Timer,
                            build_partitioner, run_environment)
 from ..core.metrics import (cross_host_replication_factor,
                             quality_from_bitmatrix)
-from ..obs import get_registry, get_tracer
+from ..obs import PipelineStallReport, get_registry, get_tracer
 from .backends import ThreadExchange
 from .state import ShardState
 
@@ -231,12 +231,13 @@ def run_worker(spec, stream, k, exchange, *, round_chunks: int = 1,
                 "shard": int(rank), "num_shards": int(world),
                 "round_chunks": int(round_chunks),
                 "assignment_in_checkpoint": True}
-        _ck.save_engine_checkpoint(rank_dir, _ck.EngineCheckpoint(
-            meta=meta, device_state=state_np, host_state=host,
-            assignment=None))
+        with tracer.span("engine.checkpoint", cat="robust",
+                         pass_index=int(pi), next_round=int(next_round),
+                         rank=rank):
+            _ck.save_engine_checkpoint(rank_dir, _ck.EngineCheckpoint(
+                meta=meta, device_state=state_np, host_state=host,
+                assignment=None))
         checkpoints_written += 1
-        tracer.complete("checkpoint", "robust", 0.0, pass_index=int(pi),
-                        next_round=int(next_round), rank=rank)
         metrics.counter("engine.checkpoints").inc()
         timer.lap("checkpoint")
         _ck.crash_after_checkpoints(checkpoints_written)
@@ -248,7 +249,7 @@ def run_worker(spec, stream, k, exchange, *, round_chunks: int = 1,
         # a round-boundary checkpoint at (pi, 0) holds pre-setup state —
         # the pass has not started; mid-pass cursors are post-setup
         if sp.setup is not None and first_round == 0:
-            with tracer.span("setup", cat="engine", phase=sp.phase):
+            with tracer.span("engine.setup", cat="engine", phase=sp.phase):
                 state = sp.setup(state)
         stall = StallClock()
         for rnd in range(first_round, layout.num_rounds):
@@ -365,7 +366,7 @@ def finalize_shard_run(worker: ShardWorkerResult, layout: ShardLayout,
     for s in worker.finals:
         for phase, v in s.meta["pass_counts"].items():
             pass_counts[phase] = pass_counts.get(phase, 0) + int(v)
-    with tracer.span("finalize", cat="engine"):
+    with tracer.span("engine.finalize", cat="engine"):
         bits, sizes, extras = part.finalize(state, pass_counts)
         bits_np, sizes_np = np.asarray(bits), np.asarray(sizes)
         quality = quality_from_bitmatrix(bits_np, sizes_np,
@@ -395,6 +396,7 @@ def finalize_shard_run(worker: ShardWorkerResult, layout: ShardLayout,
     return PartitionRunResult(
         name=part.display_name, k=k, alpha=spec.alpha,
         assignment=assignment, quality=quality, timings=worker.timer.t,
+        stages=PipelineStallReport(passes=worker.stalls).stage_seconds(),
         extras=extras,
         simulated_io_seconds=stream.simulated_io_seconds, spec=spec)
 
