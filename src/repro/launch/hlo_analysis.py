@@ -1,4 +1,6 @@
-"""Post-SPMD HLO analysis: per-device collective wire-bytes extraction.
+"""Post-SPMD HLO analysis: per-device collective wire-bytes extraction,
+and the ops inside a program's loops that produce an array of a given
+shape.
 
 Separate module (no XLA_FLAGS side effects) so tests and benchmarks can
 import it without touching jax device state.
@@ -75,3 +77,79 @@ def parse_collectives(hlo_text: str) -> dict:
     return out
 
 
+
+
+# ``[ROOT] %name = <shapes> <opcode>(<operands>)<attributes>``
+_INSTR_RE = re.compile(r"^\s*(ROOT\s+)?%(\S+) = (.*?) ([a-z][\w\-]*)\((.*)$")
+_COMP_RE = re.compile(r"^(?:ENTRY\s+)?%(\S+) .*\{\s*$")
+#: ops that pass a loop's carried arrays along without touching their words
+_PASS_THROUGH = ("parameter", "get-tuple-element", "tuple", "bitcast",
+                 "while", "call")
+
+
+def _computations(hlo_text: str) -> dict:
+    """{computation: [(name, shapes, opcode, rest of line, is_root)]}"""
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = _COMP_RE.match(line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            m = _INSTR_RE.match(line)
+            if m:
+                root, iname, shapes, op, rest = m.groups()
+                comps[name].append((iname, shapes, op, rest, bool(root)))
+    return comps
+
+
+def _writes_operand_in_place(comp: list, shape: str, updates) -> bool:
+    """A fusion whose root updates one of its parameters in place, and
+    which makes no other array of ``shape``."""
+    params = {i[0] for i in comp if i[2] == "parameter"}
+    for iname, shapes, op, rest, root in comp:
+        if root:
+            first = rest.split(",", 1)[0].strip().lstrip("%")
+            if op not in updates or first not in params:
+                return False
+        elif shape in shapes and op != "parameter":
+            return False
+    return True
+
+
+def loop_wide_ops(hlo_text: str, shape: str,
+                  updates=("dynamic-update-slice", "scatter")) -> list:
+    """``(computation, op, opcode)`` of every op in the body of a while
+    loop (or in what the body calls) that produces an array whose shape
+    text contains ``shape`` (``"s32[650000]"``), other than an in-place
+    update of a carried array: an op of ``updates``, bare or as the root of
+    a fusion, or a TPU kernel whose outputs alias its operands.  An empty
+    list says the loop touches arrays of that shape only where it writes
+    them, never in a fill or a copy.  (XLA's TPU scatter passes its whole
+    operand through VMEM while it fits there: pass ``updates=()`` to count
+    it too.)"""
+    comps = _computations(hlo_text)
+    todo = [b for c in comps.values() for _, _, op, rest, _ in c
+            if op == "while" for b in re.findall(r"body=%([\w.\-]+)", rest)]
+    seen, found = set(), []
+    while todo:
+        body = todo.pop()
+        if body in seen:
+            continue
+        seen.add(body)
+        for iname, shapes, op, rest, _ in comps[body]:
+            todo += re.findall(r"(?:body|to_apply)=%([\w.\-]+)", rest) \
+                if op in ("while", "call") else []
+            if shape not in shapes or op in _PASS_THROUGH + tuple(updates):
+                continue
+            if op == "fusion":
+                called = re.search(r"calls=%([\w.\-]+)", rest).group(1)
+                if _writes_operand_in_place(comps[called], shape, updates):
+                    continue
+            if op == "custom-call" and "tpu_custom_call" in rest and \
+                    "output_to_operand_aliasing" in rest:
+                continue
+            found.append((body, iname, op))
+    return found

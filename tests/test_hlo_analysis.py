@@ -1,4 +1,6 @@
 """Collective-bytes parser: all HLO shape formats the sweep encounters."""
+import pytest
+
 from repro.launch.hlo_analysis import parse_collectives
 
 
@@ -44,3 +46,55 @@ def test_start_done_pairs_counted_once():
         "%ars = f32[256]{0} all-reduce-start(%x), replica_groups=[1,8]<=[8]\n"
         "%ard = f32[256]{0} all-reduce-done(%ars)\n")
     assert out["count"] == 1
+
+
+_LOOP_HLO = """
+%fill (p0: s32[]) -> s32[1000] {
+  %p0 = s32[] parameter(0)
+  ROOT %b = s32[1000]{0} broadcast(%p0), dimensions={}
+}
+
+%dus (p0: s32[1000], p1: s32[1], p2: s32[]) -> s32[1000] {
+  %p0 = s32[1000]{0} parameter(0)
+  %p1 = s32[1]{0} parameter(1)
+  %p2 = s32[] parameter(2)
+  ROOT %d = s32[1000]{0} dynamic-update-slice(%p0, %p1, %p2)
+}
+
+%body (arg: (s32[], s32[1000])) -> (s32[], s32[1000]) {
+  %arg = (s32[], s32[1000]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%arg), index=0
+  %x = s32[1000]{0} get-tuple-element(%arg), index=1
+  %one = s32[1]{0} constant({1})
+  %y = s32[1000]{0} fusion(%x, %one, %i), kind=kLoop, calls=%dus
+  %z = s32[1000]{0} scatter(%y, %i, %one), to_apply=%add
+  %w = (s32[1000]{0}, s32[1]{0}) custom-call(%one, %z), custom_call_target="tpu_custom_call", output_to_operand_aliasing={{0}: (1, {})}
+  %v = s32[1000]{0} get-tuple-element(%w), index=0
+  %f = s32[1000]{0} fusion(%i), kind=kLoop, calls=%fill
+  %c = s32[1000]{0} copy(%v)
+  ROOT %t = (s32[], s32[1000]{0}) tuple(%i, %c)
+}
+
+ENTRY %main (a: s32[1000]) -> s32[1000] {
+  %a = s32[1000]{0} parameter(0)
+  %init = (s32[], s32[1000]{0}) tuple(%zero, %a)
+  %loop = (s32[], s32[1000]{0}) while(%init), condition=%cond, body=%body
+  %fill_outside = s32[1000]{0} broadcast(%zero), dimensions={}
+  ROOT %r = s32[1000]{0} get-tuple-element(%loop), index=1
+}
+"""
+
+
+@pytest.mark.parametrize("updates,flagged", [
+    (("dynamic-update-slice", "scatter"), ["f", "c"]),
+    ((), ["y", "z", "f", "c"]),
+], ids=["in-place-updates-pass", "every-update-flagged"])
+def test_loop_wide_ops(updates, flagged):
+    """A fill and a copy of a loop-carried array are flagged, in-place
+    updates (dynamic-update-slice, scatter, an aliasing TPU kernel) pass
+    unless ``updates`` excludes them, and ops outside loops never count."""
+    from repro.launch.hlo_analysis import loop_wide_ops
+    found = loop_wide_ops(_LOOP_HLO, "s32[1000]", updates=updates)
+    assert [op for _, op, _ in found] == flagged
+    assert all(body == "body" for body, _, _ in found)
+    assert loop_wide_ops(_LOOP_HLO, "s32[999]") == []

@@ -7,6 +7,50 @@ rng = np.random.default_rng(42)
 
 
 # ---------------------------------------------------------------------------
+# cluster_batch (2PS-L Phase-1 clustering micro-batch)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("sub,V", [(8, 3000), (128, 2500)])
+def test_cluster_batch_matches_ref(sub, V):
+    """Kernel (interpret mode) == jnp oracle, state and move count, over
+    successive micro-batches: hubs that several edges of one batch move
+    (contested writes), many writes on one tile of the state, padded
+    tails, clusters that fill up to the volume cap."""
+    import jax
+    from repro.kernels.cluster_batch import (batch_rows, cluster_batch_pallas,
+                                             cluster_batch_ref, from_tiles,
+                                             to_tiles)
+    r = np.random.default_rng(sub)
+    deg = jnp.asarray(r.integers(1, 9, V), jnp.int32)
+    max_vol = 40
+    kern = jax.jit(lambda a, b, g: cluster_batch_pallas(
+        a, b, g, max_vol=max_vol, interpret=True))
+    ref = jax.jit(lambda a, b, g: cluster_batch_ref(a, b, g, max_vol=max_vol))
+    v2c_t = to_tiles(jnp.arange(V, dtype=jnp.int32))
+    vol_t = to_tiles(deg)
+    hubs = r.choice(V, 4, replace=False)
+    moved = 0
+    for step in range(6):
+        e = r.integers(0, V, (sub, 2))
+        if step % 2:                      # one tile's worth of vertices
+            e = e % 200
+        on_hub = r.random(sub) < 0.5
+        e[on_hub, step % 2] = r.choice(hubs, on_hub.sum())
+        n = sub if step < 3 else int(r.integers(0, sub))
+        e[n:] = 0
+        g = batch_rows(v2c_t, vol_t, deg, jnp.asarray(e, jnp.int32),
+                       jnp.arange(sub) < n, max_vol)
+        want = ref(v2c_t, vol_t, g)
+        got = kern(v2c_t, vol_t, g)
+        for w, k in zip(want, got):
+            np.testing.assert_array_equal(np.asarray(k), np.asarray(w))
+        v2c_t, vol_t = want[0], want[1]
+        moved += int(want[2][0])
+    assert moved > 0
+    assert int(from_tiles(vol_t, V).sum()) == int(deg.sum())
+
+
+# ---------------------------------------------------------------------------
 # edge_score (2PS-L two-candidate scoring)
 # ---------------------------------------------------------------------------
 

@@ -80,3 +80,21 @@ def test_hdrf_score_compiles_for_v5e(one_chip, k, hosted):
         kw["dcn_penalty"] = 1.0
     fn = functools.partial(hdrf_choose, interpret=False, **kw)
     assert "tpu_custom_call" in _compiled_text(fn, args)
+
+
+def test_cluster_step_compiles_for_v5e(one_chip):
+    """The clustering scan at the benchmark's |V| = 650,000: the body calls
+    the micro-batch kernel and makes no |V|-wide array besides the
+    kernel's in-place writes: no fill, no copy, and no XLA scatter (which
+    passes its whole operand through VMEM on the TPU)."""
+    from repro.core.clustering import _cluster_chunk_step
+    from repro.launch.hlo_analysis import loop_wide_ops
+    V, C = 650_000, 1 << 16
+    vec = _arg((V,), jnp.int32, one_chip)
+    text = _cluster_chunk_step.lower(
+        vec, vec, vec, _arg((C, 2), jnp.int32, one_chip),
+        _arg((C,), jnp.bool_, one_chip), max_vol=125_000,
+        sub=128).compile().as_text()
+    assert "tpu_custom_call" in text
+    for shape in ("s32[650000]", "s32[635,8,128]"):
+        assert loop_wide_ops(text, shape, updates=()) == [], shape
