@@ -6,10 +6,10 @@ For each seed: the cell's graph, one job through the timed path (the
 partition CLI), and two readings of
 ``edges_vs_reference``: the program's artifact against the reference, and
 the control against the reference.  The control is the reference with the
-step a later change would be tempted to take: 2PS-L scores in bfloat16
-instead of float32; DBH degrees counted in 8 bits (saturating at 255)
-instead of 32.  The limit lies between the program's largest reading and
-the control's smallest.  Exits 2 without an accelerator, as ``bench/run.py``.
+step a later change would be tempted to take, as the traffic's reference
+file (``bench/references/<reference>.py``) defines it.  The limit lies
+between the program's largest reading and the control's smallest.  Exits
+2 without an accelerator, as ``bench/run.py``.
 """
 from __future__ import annotations
 
@@ -25,19 +25,10 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def control_assignment(edges, config: dict, traffic: dict) -> np.ndarray:
-    from bench import check, reference
-    if traffic["algorithm"] == "2psl":
-        return check.reference_assignment(edges, config, traffic,
-                                          score_dtype="bfloat16")
-    if traffic["algorithm"] == "dbh":
-        return reference.dbh(edges, int(config["k"]), saturate_at=255)
-    raise ValueError(f"no control for {traffic['algorithm']!r}")
-
-
 def readings(cell, seed: int, work_dir: str) -> dict:
-    from bench import check, harness
+    from bench import check, harness, registry
     from bench.graphs import generate, stream_order
+    refs = registry.plugin("references", cell.traffic["reference"])
     edges = stream_order(generate(cell.config, seed),
                          cell.traffic["order"], seed)
     graph = os.path.join(work_dir, "graph.bin")
@@ -47,9 +38,9 @@ def readings(cell, seed: int, work_dir: str) -> dict:
     report = harness.run_job(harness.job_argv(cell, graph, art))
     t2 = time.perf_counter()
     asg = np.fromfile(os.path.join(art, check.ASSIGNMENT), dtype=np.int32)
-    ref = check.reference_assignment(edges, cell.config, cell.traffic)
+    ref = refs.assign(edges, cell.config, cell.traffic)
     t3 = time.perf_counter()
-    ctl = control_assignment(edges, cell.config, cell.traffic)
+    ctl = refs.control(edges, cell.config, cell.traffic)
     t4 = time.perf_counter()
     return {"workload": cell.name, "seed": seed, "edges": len(edges),
             "program": check.mismatch_share(asg, ref),

@@ -11,13 +11,14 @@ All but one of those here are exact (limit 0):
   artifact the comparison reads (all jobs run the same partition);
 * ``unassigned``: edges without a partition in ``[0, k)``;
 * ``over_capacity``: edges in the largest partition beyond the hard cap
-  ``ceil(alpha * |E| / k)`` (algorithms with a cap);
+  ``ceil(alpha * |E| / k)`` (where the reference is ``CAPPED``);
 * ``rf_vs_report``, ``alpha_vs_report``: the replication factor and the
   balance that the CLI reported, against numpy's from the assignment file.
 
 The last, ``edges_vs_reference``, is the share of edges whose partition
-differs from the reference's; its limit is the cell's, set from readings
-of the program and of the control (``bench/limits/<cell>.json``).
+differs from the reference's (``bench/references/<reference>.py``, named
+by the traffic); its limit is the cell's, set from readings of the
+program and of the control (``bench/limits/<cell>.json``).
 """
 from __future__ import annotations
 
@@ -27,11 +28,9 @@ import os
 
 import numpy as np
 
-from . import reference
+from . import reference, registry
 
 ASSIGNMENT = "assignment.bin"
-#: algorithms that hold every partition under the hard capacity
-CAPPED = {"2psl"}
 MANIFEST = "manifest.json"
 
 
@@ -49,21 +48,6 @@ def recorded_digest(art_dir: str) -> str | None:
     with open(os.path.join(art_dir, MANIFEST)) as f:
         manifest = json.load(f)
     return manifest.get("integrity", {}).get("files", {}).get(ASSIGNMENT)
-
-
-def reference_assignment(edges: np.ndarray, config: dict, traffic: dict,
-                         score_dtype: str = "float32",
-                         stats: dict | None = None) -> np.ndarray:
-    k, alg = int(config["k"]), traffic["algorithm"]
-    if alg == "2psl":
-        return reference.twopsl(edges, k, float(config["alpha"]),
-                                int(traffic["chunk"]),
-                                int(traffic["micro_batch"]),
-                                float(traffic["max_vol_factor"]),
-                                score_dtype, stats)
-    if alg == "dbh":
-        return reference.dbh(edges, k)
-    raise ValueError(f"no reference for algorithm {alg!r}")
 
 
 def mismatch_share(asg: np.ndarray, ref: np.ndarray) -> float:
@@ -98,12 +82,13 @@ def judge(edges, config: dict, traffic: dict, limits: dict, art_dir: str,
         rf, sizes = reference.replication_factor(edges, asg, k)
         balance = float(sizes.max()) / (E / k)
         facts["replication_factor"] = rf
-        if traffic["algorithm"] in CAPPED:
+        ref = registry.plugin("references", traffic["reference"])
+        if ref.CAPPED:
             cap = reference.capacity(E, k, float(config["alpha"]))
             put("over_capacity", max(int(sizes.max()) - cap, 0))
         put("rf_vs_report", abs(rf - float(report["replication_factor"])))
         put("alpha_vs_report", abs(balance - float(report["alpha_measured"])))
-        ref = reference_assignment(edges, config, traffic, stats=facts)
-        put("edges_vs_reference", mismatch_share(asg, ref),
+        put("edges_vs_reference",
+            mismatch_share(asg, ref.assign(edges, config, traffic, facts)),
             float(limits["edges_vs_reference"]["limit"]))
     return checks, facts

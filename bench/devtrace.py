@@ -124,6 +124,10 @@ class SliceTracer:
 
     def __init__(self, marks):
         self.marks = sorted(marks)
+        #: seconds from ``start()`` at which each slice's session began,
+        #: and at which its trace was collected
+        self.began: list[float] = []
+        self.ended: list[float] = []
         self.xspaces: list[bytes] = []
         self.errors: list[BaseException] = []
         self._thread = threading.Thread(target=self._run, daemon=True,
@@ -145,9 +149,11 @@ class SliceTracer:
         try:
             for offset, length in self.marks:
                 time.sleep(max(0.0, self._t0 + offset - time.perf_counter()))
+                self.began.append(time.perf_counter() - self._t0)
                 session = _profiler.ProfilerSession(options)
                 time.sleep(length)
                 self.xspaces.append(session.stop())
+                self.ended.append(time.perf_counter() - self._t0)
         except Exception as e:  # noqa: BLE001 — re-raised by join()
             self.errors.append(e)
 

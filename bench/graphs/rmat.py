@@ -48,7 +48,7 @@ def _sample(scale: int, n: int, a: float, b: float, c: float,
             | dst[keep].astype(np.uint64))
 
 
-def rmat_edges(config: dict, seed: np.random.SeedSequence) -> np.ndarray:
+def edges(config: dict, seed: np.random.SeedSequence) -> np.ndarray:
     scale = int(config["scale"])
     n = int(config["edgefactor"]) << scale
     a, b, c = (float(config[x]) for x in ("a", "b", "c"))
@@ -80,7 +80,7 @@ def rmat_edges(config: dict, seed: np.random.SeedSequence) -> np.ndarray:
     labels[np.argmax(labels)] = num_vertices - 1
     new_id = np.zeros(1 << scale, np.int64)
     new_id[ids] = labels
-    edges = np.empty((len(keys), 2), np.uint32)
-    edges[:, 0] = new_id[src]
-    edges[:, 1] = new_id[dst]
-    return edges
+    out = np.empty((len(keys), 2), np.uint32)
+    out[:, 0] = new_id[src]
+    out[:, 1] = new_id[dst]
+    return out

@@ -7,8 +7,17 @@ window runs is compiled or loaded from the persistent cache.  The window
 then runs the same job back to back, one at a time, until ``seconds``
 have passed and the running job has finished.  A compilation or cache
 load inside the window fails the run.  After the window, the peak device
-memory is read, a traced run traces slices of one more job, and the last
-artifact is judged against the plain reference.
+memory is read, a traced run traces slices of more jobs, one slice to a
+job, and the last artifact is judged against the plain reference.
+
+Each slice sits at a set time into its job: the middle of its stage by
+the least time the window's jobs gave each stage, so a stall in one of
+them does not move it.  A stall in the traced job can still put a slice
+where the device runs nothing the cell's readers look for: where a
+device-trace metric of the cell reads nothing, the slices are taken
+again, placed by the traced jobs' stage times too, up to
+``TRACE_RETRIES`` more times; the metrics are then read over all slices
+taken.
 """
 from __future__ import annotations
 
@@ -20,6 +29,7 @@ import os
 import shutil
 import sys
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -29,6 +39,9 @@ from .graphs import generate, stream_order
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORK_DIR = os.path.join(ROOT, ".bench_work")
+#: times the slices are taken again at most where a device-trace metric
+#: read nothing
+TRACE_RETRIES = 2
 
 
 class NoDevice(RuntimeError):
@@ -80,13 +93,15 @@ def peak_bytes() -> int | None:
 
 
 def job_argv(cell, graph: str, art_dir: str) -> list:
+    """The partition CLI's arguments: the fixed ones, then the traffic's
+    ``cli`` flags in their order (``true`` gives a bare flag)."""
     t = cell.traffic
     argv = ["--input", graph, "--k", str(cell.config["k"]),
             "--algorithm", t["algorithm"], "--artifact-dir", art_dir,
             "--no-plan", "--alpha", str(cell.config["alpha"]),
             "--chunk-size", str(t["chunk"])]
-    if "cluster_passes" in t:
-        argv += ["--cluster-passes", str(t["cluster_passes"])]
+    for flag, value in t.get("cli", {}).items():
+        argv += [f"--{flag}"] if value is True else [f"--{flag}", str(value)]
     return argv
 
 
@@ -119,6 +134,16 @@ class LayerContext:
         return len(durs), sum(durs) / 1e9
 
 
+def _fastest(reports: list) -> dict:
+    """A report whose every stage took the least time any of ``reports``
+    gave it: where one job stalled in a stage, the others set it."""
+    stages = {}
+    for r in reports:
+        for stage, secs in r["timings_s"].items():
+            stages[stage] = min(secs, stages.get(stage, secs))
+    return {"timings_s": stages}
+
+
 def _trace_marks(report: dict, slices: list) -> list:
     """(offset, length) of each slice: the middle of each named stage of
     the job, placed by the stage times of a window job."""
@@ -129,6 +154,32 @@ def _trace_marks(report: dict, slices: list) -> list:
         start += secs
     return [(max(0.0, at[s["phase"]] - s["seconds"] / 2), s["seconds"])
             for s in slices if s["phase"] in at]
+
+
+def _traced_jobs(cell, graph: str, work_dir: str, marks: list) -> list:
+    """The ``devtrace.Slice``s at ``marks``, each taken in a job of its
+    own (collecting a slice of the clustering scan takes the profiler 4-7 s
+    on a v5e, past the next mark of the same job), and the jobs' reports."""
+    trace_art = os.path.join(work_dir, "traced")
+    slices, reports = [], []
+    for offset, length in marks:
+        tracer = devtrace.SliceTracer([(offset, length)])
+        tracer.start()
+        report = run_job(job_argv(cell, graph, trace_art))
+        (xs,) = tracer.join()
+        got = devtrace.reduce_xspace(xs)
+        runs = Counter()
+        for s in got:
+            runs.update({m: len(d) for m, d in s.modules.items()})
+        say(f"trace: slice of {length} s planned at {offset:.3f} s began "
+            f"at {tracer.began[0]:.3f} s, collected at {tracer.ended[0]:.3f}"
+            f" s: {sum(s.window_ns for s in got) / 1e9:.4f} s of device "
+            f"window, whole executions {dict(runs)}; the job's stages "
+            f"{report['timings_s']}")
+        slices += got
+        reports.append(report)
+        shutil.rmtree(trace_art, ignore_errors=True)
+    return slices, reports
 
 
 def _breakdown(slices: list) -> dict:
@@ -198,16 +249,10 @@ def _run(cell, seed, seconds, trace, t_start, device, work_dir) -> dict:
         f"{peak}: the partition job sets it (set-up puts nothing on the "
         f"device but one job like the window's)")
 
-    slices = []
+    slices, traced = [], []
     if trace:
-        marks = _trace_marks(jobs[-1], cell.traffic["trace_slices"])
-        tracer = devtrace.SliceTracer(marks)
-        trace_art = os.path.join(work_dir, "traced")
-        tracer.start()
-        run_job(job_argv(cell, graph, trace_art))
-        for xs in tracer.join():
-            slices += devtrace.reduce_xspace(xs)
-        shutil.rmtree(trace_art, ignore_errors=True)
+        marks = _trace_marks(_fastest(jobs), cell.traffic["trace_slices"])
+        slices, traced = _traced_jobs(cell, graph, work_dir, marks)
 
     from repro.core import PartitionArtifact
     t_c = time.perf_counter()
@@ -234,10 +279,25 @@ def _run(cell, seed, seconds, trace, t_start, device, work_dir) -> dict:
         ctx = LayerContext(jobs=jobs, slices=slices, config=cell.config,
                            traffic=cell.traffic, peaks=device["peaks"],
                            facts=facts)
-        for m, read in cell.per_layer:
-            value = read(ctx)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        values = {m["name"]: read(ctx) for m, read in cell.per_layer}
+        for _ in range(TRACE_RETRIES):
+            missing = [m["name"] for m, _ in cell.per_layer
+                       if m["source"] == "device_trace"
+                       and values[m["name"]] is None]
+            if not missing:
+                break
+            say(f"trace: {', '.join(missing)} read nothing from "
+                f"{len(slices)} slices; taking them again")
+            marks = _trace_marks(_fastest(jobs + traced),
+                                 cell.traffic["trace_slices"])
+            more, reports = _traced_jobs(cell, graph, work_dir, marks)
+            slices += more
+            traced += reports
+            values = {m["name"]: read(ctx) for m, read in cell.per_layer}
+        for m, _ in cell.per_layer:
+            if values[m["name"]] is not None:
+                metrics[m["name"]] = {"value": values[m["name"]],
+                                      "unit": m["unit"]}
         chips = max(len({s.device for s in slices}), 1)
         dev["busy_s"] = sum(s.busy_ns for s in slices) / 1e9 / chips
         dev["window_s"] = sum(s.window_ns for s in slices) / 1e9 / chips

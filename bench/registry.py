@@ -8,10 +8,21 @@ its own, found by that name:
 * a traffic mix: ``bench/traffic/<traffic>.json``;
 * a cell's correctness limits: ``bench/limits/<workload>.json``;
 * a per-layer metric: ``bench/metrics/<name>.py``, whose ``read(ctx)``
-  returns the value or ``None`` when there is nothing to read.
+  returns the value or ``None`` when there is nothing to read;
+* the configuration's graph generator: ``bench/graphs/<generator>.py``,
+  whose ``edges(config, seed_seq)`` returns the ``(E, 2)`` uint32 edges;
+* the traffic's stream order: ``bench/orders/<order>.py``, whose
+  ``order(edges, seed_seq)`` returns the edges as the job streams them;
+* the traffic's plain reference: ``bench/references/<reference>.py``,
+  whose ``assign(edges, config, traffic, stats)`` computes each edge's
+  partition, ``control(edges, config, traffic)`` the same one step worse
+  (what the calibration must see fail), and ``CAPPED`` says whether every
+  partition stays under the hard capacity.
 
-So a cell is added with data files and a ``BENCHMARK.json`` entry, and no
-edit of code here.
+The traffic's ``cli`` object, ``{"flag-name": value}``, adds engine flags
+to the job's command line.  So a cell, a deployment or an algorithm is
+added with new files and a ``BENCHMARK.json`` entry, and no edit of code
+here; every name is checked by ``load``.
 """
 from __future__ import annotations
 
@@ -26,6 +37,16 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+#: code found by name: directory under ``bench/`` -> what each file defines
+PLUGINS = {"metrics": ("read",), "graphs": ("edges",),
+           "orders": ("order",),
+           "references": ("assign", "control", "CAPPED")}
+#: partition CLI flags the harness sets itself, which ``cli`` may not name
+FIXED_FLAGS = {"input", "k", "algorithm", "artifact-dir", "no-plan", "alpha",
+               "chunk-size"}
+FLAG = re.compile(r"^[a-z][a-z0-9-]{0,63}$")
+#: the checkout that holds this package
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class BenchmarkError(ValueError):
@@ -57,16 +78,31 @@ class Cell:
     per_layer: list = field(default_factory=list)    # (entry, reader)
 
 
-def load_reader(path: str):
-    """The ``read`` function of a per-layer metric file."""
-    name = os.path.basename(path)[:-3].replace(".", "_")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+def plugin(kind: str, name, root: str = ROOT):
+    """The module ``bench/<kind>/<name>.py`` of the checkout at ``root``,
+    checked to define what ``PLUGINS`` asks of its kind."""
+    _need(isinstance(name, str) and bool(NAME.fullmatch(name)),
+          f"bad {kind} name {name!r}")
+    path = os.path.join(root, "bench", kind, name + ".py")
+    _need(os.path.isfile(path), f"no {kind} file {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_{re.sub(r'[^A-Za-z0-9_]', '_', name)}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    _need(callable(getattr(mod, "read", None)),
-          f"{path} defines no read(ctx)")
-    return mod.read
+    for attr in PLUGINS[kind]:
+        _need(hasattr(mod, attr), f"{path} defines no {attr}")
+    return mod
+
+
+def _check_cli(name: str, cli) -> None:
+    _need(isinstance(cli, dict), f"{name}: cli must be an object")
+    for flag, value in cli.items():
+        _need(bool(FLAG.fullmatch(flag)) and flag not in FIXED_FLAGS,
+              f"{name}: bad cli flag {flag!r}")
+        _need(value is True or (isinstance(value, (int, float, str))
+                                and not isinstance(value, bool)),
+              f"{name}: cli flag {flag!r} takes a number, a string or "
+              f"true, not {value!r}")
 
 
 def _metric_applies(entry: dict, cell: str, reported: set) -> bool:
@@ -95,18 +131,19 @@ def load(root: str) -> dict:
         _need(m["better"] in ("lower", "higher"), f"bad better in {m}")
         _need(m["source"] in SOURCES, f"bad source in {m}")
     for m in bench["per_layer"]:
-        path = os.path.join(root, "bench", "metrics", m["name"] + ".py")
-        _need(os.path.isfile(path), f"no reader {path}")
+        plugin("metrics", m["name"], root)
     for c in configs.values():
-        _need(os.path.isfile(os.path.join(root, c["file"])),
-              f"missing configuration file {c['file']}")
+        conf = _load_json(os.path.join(root, c["file"]))
+        plugin("graphs", conf.get("generator"), root)
     for w in bench["workloads"]:
         _need(w["config"] in configs, f"{w['name']}: unknown config")
         _need(w["chips"] in (1, 4), f"{w['name']}: chips must be 1 or 4")
-        for part in ("traffic", "limits"):
-            key = w["traffic"] if part == "traffic" else w["name"]
-            path = os.path.join(root, "bench", part, key + ".json")
-            _need(os.path.isfile(path), f"{w['name']}: missing {path}")
+        _load_json(os.path.join(root, "bench", "limits", w["name"] + ".json"))
+        traffic = _load_json(os.path.join(root, "bench", "traffic",
+                                          w["traffic"] + ".json"))
+        plugin("orders", traffic.get("order"), root)
+        plugin("references", traffic.get("reference"), root)
+        _check_cli(w["name"], traffic.get("cli", {}))
     return bench
 
 
@@ -121,8 +158,7 @@ def cell(root: str, name: str) -> Cell:
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or name in m["workloads"]]
     reported = {m["name"] for m in e2e}
-    layer = [(m, load_reader(os.path.join(root, "bench", "metrics",
-                                          m["name"] + ".py")))
+    layer = [(m, plugin("metrics", m["name"], root).read)
              for m in bench["per_layer"]
              if _metric_applies(m, name, reported)]
     return Cell(

@@ -5,55 +5,63 @@ in a lower precision, or with narrower counters) fails the cell's limit;
 and a run driven through the harness with the timed path broken
 underneath comes out not correct, once for each fault a one-chip cell
 can have.  The harness's look for a chip is skipped: these run on the CPU.
+Every workload of ``BENCHMARK.json`` is tested, at the CPU size its
+configuration file gives under ``small``.
 """
+import json
 import os
 
 import numpy as np
 import pytest
 
-from bench import calibrate, check, harness, reference, registry
+from bench import check, harness, reference, registry
 from bench.graphs import generate, stream_order
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-SMALL = {"graph500-rmat-s20-k256": {"scale": 13, "edges": 100_000,
-                                    "vertices": 7_000}}
-CELLS = ["rmat20-k256.2psl", "rmat20-k256.dbh"]
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    WORKLOADS = [w["name"] for w in json.load(_f)["workloads"]]
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1,
        "peaks": {"flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}}
 
 
 def small_cell(name: str) -> registry.Cell:
-    """The cell with a CPU-sized graph, k=32 and 8,192-edge chunks."""
+    """The cell with its configuration's CPU-sized graph, k=32 and
+    8,192-edge chunks."""
     cell = registry.cell(ROOT, name)
-    cell.config.update(SMALL[cell.config_name], k=32)
+    assert isinstance(cell.config.get("small"), dict), \
+        f"{cell.config_name} gives no CPU size under 'small'"
+    cell.config.update(cell.config["small"], k=32)
     cell.traffic["chunk"] = 8192
     return cell
 
 
-def program_assignment(cell, edges):
-    from repro.core import InMemoryEdgeStream, run_spec, spec_for
-    over = {"alpha": cell.config["alpha"], "chunk_size": cell.traffic["chunk"]}
-    if "cluster_passes" in cell.traffic:
-        over["cluster_passes"] = cell.traffic["cluster_passes"]
-    res = run_spec(spec_for(cell.traffic["algorithm"], **over),
-                   InMemoryEdgeStream(edges.astype(np.int32)),
-                   int(cell.config["k"]))
-    return np.asarray(res.assignment), res
+def program_assignment(cell, edges, work_dir):
+    """One job over ``edges`` through the partition CLI with the timed
+    path's arguments: (assignment, the CLI's report)."""
+    graph = os.path.join(work_dir, "graph.bin")
+    edges.tofile(graph)
+    art = os.path.join(work_dir, "artifact")
+    report = harness.run_job(harness.job_argv(cell, graph, art))
+    return np.fromfile(os.path.join(art, check.ASSIGNMENT), np.int32), report
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_program_equals_reference_and_control_fails(name):
+@pytest.mark.parametrize("name", WORKLOADS)
+def test_program_equals_reference_and_control_fails(name, tmp_path):
     cell = small_cell(name)
+    refs = registry.plugin("references", cell.traffic["reference"])
     limit = cell.limits["edges_vs_reference"]["limit"]
     for seed in (3, 2**31 + 11):
-        edges = stream_order(generate(cell.config, seed), "shuffled", seed)
-        asg, res = program_assignment(cell, edges)
-        ref = check.reference_assignment(edges, cell.config, cell.traffic)
+        edges = stream_order(generate(cell.config, seed),
+                             cell.traffic["order"], seed)
+        work = tmp_path / str(seed)
+        work.mkdir()
+        asg, report = program_assignment(cell, edges, str(work))
+        ref = refs.assign(edges, cell.config, cell.traffic)
         assert check.mismatch_share(asg, ref) <= limit
         rf, _ = reference.replication_factor(edges, ref, cell.config["k"])
-        assert rf == res.quality.replication_factor
-    ctl = calibrate.control_assignment(edges, cell.config, cell.traffic)
+        assert rf == report["replication_factor"]
+    ctl = refs.control(edges, cell.config, cell.traffic)
     assert check.mismatch_share(ctl, ref) > 10 * max(limit, 1e-3)
 
 
@@ -62,7 +70,7 @@ def run(cell, tmp_path, monkeypatch):
     return harness.run_cell(cell, 2**31 + 5, 0.0, False, 0.0, CPU)
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", WORKLOADS)
 def test_sound_run_is_correct(name, tmp_path, monkeypatch):
     res = run(small_cell(name), tmp_path, monkeypatch)
     assert res["correct"] and res["failed"] == 0
@@ -113,10 +121,17 @@ FAULTS = {"state_unchanged": _state_unchanged,
           "answer_altered": _answer_altered}
 
 
-# DBH carries no state from one chunk to the next, so it has no
-# state-unchanged fault
-CASES = [(n, f) for n in CELLS for f in sorted(FAULTS)
-         if not (f == "state_unchanged" and n.endswith(".dbh"))]
+def _clusters(name: str) -> bool:
+    """Whether the cell's algorithm runs Phase-1 clustering, the state that
+    ``state_unchanged`` breaks.  Hash partitioners such as DBH carry no
+    state from one chunk to the next, so they cannot have that fault."""
+    from repro.core import spec_for
+    traffic = registry.cell(ROOT, name).traffic
+    return getattr(spec_for(traffic["algorithm"]), "cluster_passes", 0) > 0
+
+
+CASES = [(n, f) for n in WORKLOADS for f in sorted(FAULTS)
+         if f != "state_unchanged" or _clusters(n)]
 
 
 @pytest.mark.parametrize("name,fault", CASES)
